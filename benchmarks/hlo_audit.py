@@ -102,8 +102,13 @@ def worker() -> list[dict]:
         jaxpr = jax.make_jaxpr(step)(*args)
         hlo = None
         if compile_hlo:
+            # a wrapped step keeps the runtime's input shardings: left
+            # unspecified, XLA's propagation moves the params to wherever
+            # the wrapper constrains them and no repair shows in the HLO
             jit = (hp.jit_train_step(donate=False) if wrap is None
-                   else compat.jit(step))
+                   else compat.jit(step, in_shardings=(
+                       hp.shardings(hp.param_specs),
+                       hp.shardings(hp.opt_state_specs()), None)))
             hlo = jit.lower(*args).compile().as_text()
         return hlo, jaxpr
 

@@ -112,8 +112,10 @@ def _paired_pass(step_fn, params, opt, batch, sink, timer,
 
 
 def run() -> dict:
+    import jax
+
     from repro import obs
-    from repro.core.cluster import TPU_V5E_POD
+    from repro.core.cluster import device_peak_flops
     from repro.core.profiler_model import profile_model
     from repro.runtime.elastic import DriftReplanAdvisor
 
@@ -133,7 +135,7 @@ def run() -> dict:
             registry = obs.MetricsRegistry()
             timer = obs.StepTimer(registry, tokens_per_step=tokens,
                                   flops_per_step=flops,
-                                  peak_flops=TPU_V5E_POD.peak_flops)
+                                  peak_flops=device_peak_flops(jax.devices()[0]))
             drift = obs.DriftMonitor(warm)
             sink = obs.RunSink.create(pathlib.Path(td) / f"pass{p}",
                                       meta={"arch": cfg.name, "mode": "bench"})

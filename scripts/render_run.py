@@ -74,6 +74,9 @@ def render(records: list[dict]) -> str:
         if mfus:
             lines.append(f"  MFU         mean {100 * sum(mfus) / len(mfus):.2f}%   "
                          f"last {100 * mfus[-1]:.2f}%")
+        else:
+            lines.append("  MFU         not measured (the device has no "
+                         "peak in repro.core.cluster)")
         if losses:
             lines.append(f"  loss        first {losses[0]:.4f}   "
                          f"last {losses[-1]:.4f}")

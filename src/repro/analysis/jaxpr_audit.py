@@ -21,11 +21,10 @@ sub-jaxprs — and check the program against the plan:
   the clean ``remat='none'`` step still stages small dot-free ``remat2``
   regions from library internals — hence the contains-a-dot requirement.
 * **GALV093 host-callback-in-step** (jaxpr side) — ``pure_callback`` /
-  ``io_callback`` / debug prints staged inside the step sync the host every
-  tick.
+  ``io_callback`` / debug prints (``debug_print`` on JAX 0.9) staged inside
+  the step sync the host every tick.
 
-Verified on JAX 0.4.37: the checkpoint primitive is named ``remat2``
-(``remat`` / ``checkpoint`` are accepted for other versions).
+Verified on JAX 0.9.0: the checkpoint primitive is named ``remat2``.
 """
 from __future__ import annotations
 
@@ -34,13 +33,13 @@ from collections import Counter
 
 from repro.analysis.plan_check import Diagnostic
 
-#: jax.checkpoint's staged primitive across supported JAX versions
-REMAT_PRIMITIVES = ("remat2", "remat", "checkpoint")
+#: jax.checkpoint's staged primitive
+REMAT_PRIMITIVES = ("remat2",)
 
 #: host-synchronizing primitives that must never stage inside the step
 CALLBACK_PRIMITIVES = ("pure_callback", "io_callback", "debug_callback",
-                       "host_callback_call", "outside_call", "infeed",
-                       "outfeed")
+                       "debug_print", "host_callback_call", "outside_call",
+                       "infeed", "outfeed")
 
 #: matmul-class compute primitives inspected for dtype drift
 _DOT_PRIMITIVES = ("dot_general", "conv_general_dilated")

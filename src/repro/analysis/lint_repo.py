@@ -7,8 +7,9 @@ this module must import cleanly without them).  Exposed as
 Rules:
 
 * **compat-jit / compat-shard-map / compat-mesh / compat-cost-analysis** —
-  every version-sensitive JAX API (``jax.jit``, ``jax.shard_map``, ``Mesh(``
-  construction, ``.cost_analysis()``) must route through ``repro/compat.py``.
+  the mesh/sharding-sensitive JAX APIs (``jax.jit``, ``jax.shard_map``,
+  ``Mesh(`` construction, ``.cost_analysis()``) must route through
+  ``repro/compat.py``, which owns the axis-type and manual-axis decisions.
   Scope: ``src/repro``, ``benchmarks/``, ``scripts/`` and ``examples/`` —
   the quickstarts are the repo's public face and must model the supported
   API, so they get the full rule set (tests deliberately exercise raw JAX —
@@ -185,8 +186,8 @@ class _Visitor(ast.NodeVisitor):
                                "jax directly")
         if mod == "jax.experimental.shard_map":
             self._flag(node, "compat-shard-map",
-                       "use repro.compat.shard_map — it lowers the new "
-                       "signature to whichever JAX is installed")
+                       "use repro.compat.shard_map, not the "
+                       "experimental module")
         self.generic_visit(node)
 
     # ---------------------------------------------------------- uses
@@ -196,7 +197,7 @@ class _Visitor(ast.NodeVisitor):
             if node.attr == "jit":
                 self._flag(node, "compat-jit",
                            "jax.jit bypasses the compat shim — use "
-                           "repro.compat.jit (it filters unsupported flags)")
+                           "repro.compat.jit")
             elif node.attr == "shard_map":
                 self._flag(node, "compat-shard-map",
                            "jax.shard_map bypasses the compat shim — use "
@@ -219,8 +220,8 @@ class _Visitor(ast.NodeVisitor):
                 and not (isinstance(fn.value, ast.Attribute)
                          and fn.value.attr == "compat")):
             self._flag(node, "compat-cost-analysis",
-                       ".cost_analysis() returns list-vs-dict across JAX "
-                       "releases — use repro.compat.cost_analysis(obj)")
+                       "use repro.compat.cost_analysis(obj), which "
+                       "returns a flat dict")
         if name == "ParamDef":
             self._check_paramdef(node)
         if name == "ServingEngine":

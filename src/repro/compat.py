@@ -1,78 +1,28 @@
-"""Version-compat shim: every version-sensitive JAX API goes through here.
+"""Mesh / sharding / shard_map / jit entry points for the installed JAX (0.9).
 
-The runtime targets a range of JAX releases (0.4.x LTS through current) and
-must run hermetically — no network, no optional wheels.  Rather than
-scattering ``hasattr(jax, ...)`` probes through the parallel/runtime layers,
-this module centralizes the differences:
+The parallel runtime imports these names from here, never from
+``jax.sharding``/``jax.experimental`` directly, so that one module owns the
+decisions the runtime depends on:
 
-* **shard_map** moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-  and renamed its knobs (``auto``/``check_rep`` -> ``axis_names``/
-  ``check_vma``).  :func:`shard_map` takes the *new* signature and lowers it
-  to whichever the installed JAX provides.
-
-* **abstract mesh / axis types** (``jax.sharding.get_abstract_mesh`` /
-  ``AxisType``) do not exist on older releases.  Inside a partial-auto
-  shard_map region the new API tells ``lc()`` which mesh axes are Manual; on
-  old JAX we track the manual axis set ourselves (a thread-local pushed by
-  :func:`shard_map` while the body traces) and degrade to a concrete-mesh
-  ``with_sharding_constraint`` over the remaining auto axes.
-  :func:`current_mesh_context` is the single query point.
-
-* **mesh construction** (``jax.make_mesh``) gained a helper late in 0.4.x;
-  :func:`make_mesh` falls back to reshaping ``jax.devices()`` by hand.
-
-* **jit flags** come and go (``donate_argnames``, ``out_shardings``, ...).
-  :func:`jit` filters kwargs the installed ``jax.jit`` does not accept, so
-  callers can always pass the full modern set.
-
-Import from here, never from ``jax.sharding``/``jax.experimental`` directly,
-when touching mesh/sharding/shard_map APIs in the parallel runtime.
+* **axis types.** ``jax.make_mesh`` builds *Explicit* axes by default, and the
+  runtime's sharding constraints (``jax.lax.with_sharding_constraint`` in
+  ``parallel/axes.py``, ``runtime/train.py``, ``parallel/pipeline.py``)
+  refer to *Auto* axes only.  :func:`make_mesh` therefore asks for Auto axes.
+* **manual axes.** Inside a partial-auto ``shard_map`` body the abstract-mesh
+  context marks the manual axes; :func:`current_mesh_context` is the single
+  query point ``lc()`` uses to drop them from its rules.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-import threading
 from typing import Any, Callable, Iterable, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401  (re-exported)
+from jax.sharding import AbstractMesh, AxisType, Mesh, NamedSharding, PartitionSpec  # noqa: F401  (re-exported)
 
 P = PartitionSpec
 
-try:  # AbstractMesh is present from late 0.4.x on; older releases lack it.
-    from jax.sharding import AbstractMesh  # noqa: F401
-    HAS_ABSTRACT_MESH_TYPE = True
-except ImportError:  # pragma: no cover - not reachable on the pinned JAX
-    AbstractMesh = None  # type: ignore[assignment]
-    HAS_ABSTRACT_MESH_TYPE = False
-
-
-def _version_tuple(v: str) -> tuple[int, ...]:
-    parts = []
-    for tok in v.split(".")[:3]:
-        digits = "".join(ch for ch in tok if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple[int, ...] = _version_tuple(jax.__version__)
-
-# ---------------------------------------------------------------------------
-# feature probes (computed once at import; monkeypatchable in tests)
-# ---------------------------------------------------------------------------
-
-#: new-style abstract-mesh context API (jax.sharding.get_abstract_mesh +
-#: AxisType) — the mechanism lc() uses to detect Manual axes on new JAX.
-HAS_ABSTRACT_MESH_API: bool = (
-    hasattr(jax.sharding, "get_abstract_mesh") and hasattr(jax.sharding, "AxisType")
-)
-
-#: top-level jax.shard_map with (mesh=, in_specs=, out_specs=, axis_names=,
-#: check_vma=) keywords.
-HAS_TOPLEVEL_SHARD_MAP: bool = hasattr(jax, "shard_map")
-
-HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
+#: ``jax.jit``; the ``compat-jit`` lint rule routes every caller through here.
+jit = jax.jit
 
 
 # ---------------------------------------------------------------------------
@@ -81,102 +31,35 @@ HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
 
 def make_mesh(shape: Iterable[int], axes: Iterable[str],
               devices: Optional[Iterable[Any]] = None) -> Mesh:
-    """``jax.make_mesh`` when available; manual devices-reshape otherwise.
+    """``jax.make_mesh`` with every axis of type Auto.
 
     ``devices`` restricts the mesh to an explicit device subset — the live
     elastic-resize path (runtime/resize.py) builds the shrunk mesh over the
     surviving devices while the departed ones idle.
     """
     shape, axes = tuple(shape), tuple(axes)
-    dev_list = list(devices) if devices is not None else None
-    if HAS_MAKE_MESH:
-        if dev_list is None:
-            return jax.make_mesh(shape, axes)
-        try:
-            return jax.make_mesh(shape, axes, devices=dev_list)
-        except TypeError:  # pragma: no cover - jax.make_mesh without devices=
-            pass
-    import numpy as np
-
-    n = 1
-    for s in shape:
-        n *= s
-    pool = dev_list if dev_list is not None else jax.devices()
-    return Mesh(np.asarray(pool[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=None if devices is None else list(devices))
 
 
-def abstract_mesh(shape: Iterable[int], axes: Iterable[str]):
-    """Device-free mesh for sharding-rule derivation, across the
-    ``AbstractMesh(shape, axis_names)`` vs ``AbstractMesh(((name, size), ...))``
-    constructor change."""
-    if AbstractMesh is None:  # pragma: no cover - not reachable on pinned JAX
-        raise RuntimeError("this JAX release has no AbstractMesh")
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
-# ---------------------------------------------------------------------------
-# manual-axis bookkeeping (old-JAX fallback for the abstract-mesh context)
-# ---------------------------------------------------------------------------
-
-_MANUAL = threading.local()
-
-
-def _manual_stack() -> list[frozenset[str]]:
-    if not hasattr(_MANUAL, "stack"):
-        _MANUAL.stack = []
-    return _MANUAL.stack
-
-
-class _manual_axes_ctx:
-    """Context manager marking ``axes`` as Manual while a shard_map body
-    traces (old-JAX path; the new API exposes this via the abstract mesh)."""
-
-    def __init__(self, axes: frozenset[str]):
-        self.axes = axes
-
-    def __enter__(self):
-        _manual_stack().append(self.axes)
-        return self
-
-    def __exit__(self, *exc):
-        _manual_stack().pop()
-        return False
-
-
-def tracked_manual_axes() -> frozenset[str]:
-    """Union of manual axes from the (possibly nested) shard_map regions the
-    current thread is tracing.  Empty outside any region."""
-    out: frozenset[str] = frozenset()
-    for axes in _manual_stack():
-        out = out | axes
-    return out
+def abstract_mesh(shape: Iterable[int], axes: Iterable[str]) -> AbstractMesh:
+    """Device-free mesh (Auto axes) for sharding-rule derivation."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def current_mesh_context(mesh: Mesh) -> tuple[Any, frozenset[str]]:
     """(mesh to build sharding constraints on, currently-Manual axis names).
 
-    New JAX: when an abstract mesh context matching ``mesh``'s axes is
-    active (i.e. we are inside a shard_map region), constraints must be built
-    on *it*, and its Manual-typed axes must be dropped from the rules.
-
-    Old JAX: there is no abstract-mesh API; constraints are built on the
-    concrete ``mesh`` and the manual set comes from our own shard_map
-    wrapper's bookkeeping — the degraded path the docstring of
-    :mod:`repro.compat` describes.
+    When an abstract mesh context matching ``mesh``'s axes is active (i.e. we
+    are inside a shard_map region), constraints must be built on *it*, and its
+    Manual-typed axes must be dropped from the rules.
     """
-    if HAS_ABSTRACT_MESH_API:
-        ctx = jax.sharding.get_abstract_mesh()
-        if ctx is not None and not ctx.empty and set(ctx.axis_names) == set(mesh.axis_names):
-            manual = frozenset(
-                n for n, t in zip(ctx.axis_names, ctx.axis_types)
-                if t == jax.sharding.AxisType.Manual)
-            return ctx, manual
-        return mesh, frozenset()
-    return mesh, tracked_manual_axes() & frozenset(mesh.axis_names)
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx is not None and not ctx.empty and set(ctx.axis_names) == set(mesh.axis_names):
+        manual = frozenset(n for n, t in zip(ctx.axis_names, ctx.axis_types)
+                           if t == AxisType.Manual)
+        return ctx, manual
+    return mesh, frozenset()
 
 
 # ---------------------------------------------------------------------------
@@ -192,27 +75,12 @@ def shard_map(
     axis_names: Optional[Iterable[str]] = None,
     check_vma: bool = True,
 ) -> Callable:
-    """New-signature shard_map lowered to the installed JAX.
-
-    ``axis_names`` is the set of mesh axes the body is *manual* over (the
-    new-API meaning); remaining axes stay auto so GSPMD constraints keep
-    working inside.  ``None`` means fully manual (every axis).
-    """
-    manual = frozenset(axis_names) if axis_names is not None else frozenset(mesh.axis_names)
-    if HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             axis_names=set(manual), check_vma=check_vma)
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    @functools.wraps(f)
-    def tracked(*args, **kwargs):
-        with _manual_axes_ctx(manual):
-            return f(*args, **kwargs)
-
-    auto = frozenset(mesh.axis_names) - manual
-    return _legacy_shard_map(tracked, mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_rep=check_vma, auto=auto)
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes the body is
+    *manual* over (remaining axes stay auto so GSPMD constraints keep working
+    inside).  ``None`` means fully manual (every axis)."""
+    manual = set(axis_names) if axis_names is not None else set(mesh.axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=manual, check_vma=check_vma)
 
 
 # ---------------------------------------------------------------------------
@@ -220,66 +88,7 @@ def shard_map(
 # ---------------------------------------------------------------------------
 
 def cost_analysis(computation) -> dict:
-    """Normalized ``.cost_analysis()`` for a Lowered/Compiled computation.
-
-    0.4.x releases return a single-element list of per-program metric dicts;
-    newer releases return the dict directly.  Either way the caller gets a
-    flat ``{metric: value}`` dict (empty when XLA reports nothing).
-    """
+    """``.cost_analysis()`` of a Lowered/Compiled computation as a flat
+    ``{metric: value}`` dict (empty when XLA reports nothing)."""
     ca = computation.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
     return dict(ca) if ca else {}
-
-
-# ---------------------------------------------------------------------------
-# jit flag filtering
-# ---------------------------------------------------------------------------
-
-# ---------------------------------------------------------------------------
-# profiler / naming annotations
-# ---------------------------------------------------------------------------
-
-def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` when the installed JAX has it,
-    else a ``nullcontext`` — host-side trace spans (``repro.obs``) enter this
-    so they appear in captured JAX profiles without requiring one."""
-    import contextlib
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except (AttributeError, TypeError):  # pragma: no cover - ancient JAX
-        return contextlib.nullcontext()
-
-
-def named_scope(name: str):
-    """``jax.named_scope(name)`` (names ops in HLO/profiles inside traced
-    code) with a ``nullcontext`` fallback on releases that lack it."""
-    import contextlib
-    try:
-        return jax.named_scope(name)
-    except (AttributeError, TypeError):  # pragma: no cover - ancient JAX
-        return contextlib.nullcontext()
-
-
-@functools.lru_cache(maxsize=1)
-def _jit_params() -> frozenset[str]:
-    try:
-        return frozenset(inspect.signature(jax.jit).parameters)
-    except (TypeError, ValueError):  # pragma: no cover - C-implemented jit
-        return frozenset()
-
-
-def jit(fn: Callable, **kwargs) -> Callable:
-    """``jax.jit`` that drops keyword flags the installed JAX lacks.
-
-    Flags with ``None`` values are dropped too, so callers can write
-    ``compat.jit(f, in_shardings=shardings_or_none)`` without branching.
-    """
-    supported = _jit_params()
-    filtered = {}
-    for k, v in kwargs.items():
-        if v is None and k in ("in_shardings", "out_shardings"):
-            continue
-        if not supported or k in supported:
-            filtered[k] = v
-    return jax.jit(fn, **filtered)

@@ -9,6 +9,18 @@ from __future__ import annotations
 import dataclasses
 
 
+#: Published bf16 peak FLOP/s of one chip, keyed by ``jax.Device.device_kind``
+#: as JAX reports it.  Source: Google Cloud documentation, "TPU v5e"
+#: (197 TFLOP/s bf16).  A kind missing here has no MFU — never another
+#: chip's peak.
+PEAK_FLOPS_BY_DEVICE_KIND = {"TPU v5 lite": 197e12}
+
+
+def device_peak_flops(device) -> float:
+    """Peak bf16 FLOP/s of ``device``'s chip; 0.0 (no MFU) when unknown."""
+    return PEAK_FLOPS_BY_DEVICE_KIND.get(device.device_kind, 0.0)
+
+
 @dataclasses.dataclass(frozen=True)
 class ClusterSpec:
     name: str
@@ -35,7 +47,7 @@ class ClusterSpec:
 TPU_V5E_POD = ClusterSpec(
     name="tpu-v5e-256",
     chips=256,
-    peak_flops=197e12,
+    peak_flops=PEAK_FLOPS_BY_DEVICE_KIND["TPU v5 lite"],
     hbm_bytes=16e9,
     hbm_bw=819e9,
     intra_bw=50e9,                 # ~50 GB/s/link ICI (assignment constant)

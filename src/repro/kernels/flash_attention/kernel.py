@@ -71,8 +71,8 @@ def _flash_fwd_kernel(*refs, causal: bool, positional: bool, residuals: bool,
 
     if causal:
         if positional:
-            q_pos = qp_ref[0][:, None]                 # (bq, 1) global positions
-            k_pos = kp_ref[0][None, :]                 # (1, bk)
+            q_pos = qp_ref[0]                          # (bq, 1) global positions
+            k_pos = kp_ref[0]                          # (1, bk)
         else:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
@@ -95,8 +95,8 @@ def _flash_fwd_kernel(*refs, causal: bool, positional: bool, residuals: bool,
     def _finalize():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
         if residuals:
-            m_out[0] = m_ref[...]
-            l_out[0] = l_ref[...]
+            m_out[0] = m_ref[...][None, :]
+            l_out[0] = l_ref[...][None, :]
 
 
 def _tile_positions(pos, B: int, H: int, S: int):
@@ -153,21 +153,26 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
     ]
     inputs = [qt, kt, vt]
     if positional:
+        # The TPU tiling rule applies to a block's last two dims: each must
+        # be a multiple of (8, 128) or span the array.  A unit dim spanning
+        # the array satisfies it, and puts q positions on sublanes and k
+        # positions on lanes, as the (bq, bk) mask wants them.
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_kv), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b, 0, j)),
         ]
-        inputs += [_tile_positions(q_pos, B, H, Sq),
-                   _tile_positions(k_pos, B, H, Sk)]
+        inputs += [_tile_positions(q_pos, B, H, Sq)[:, :, None],
+                   _tile_positions(k_pos, B, H, Sk)[:, None, :]]
 
     out_specs = pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0))
     out_shape = jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype)
     if return_residuals:
-        stat_spec = pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))
+        # (B·H, 1, Sq): the unit dim spans the array (tiling rule above)
+        stat_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
         out_specs = [out_specs, stat_spec, stat_spec]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B * H, Sq), jnp.float32),
-                     jax.ShapeDtypeStruct((B * H, Sq), jnp.float32)]
+                     jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
+                     jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32)]
 
     out = pl.pallas_call(
         kernel,

@@ -86,6 +86,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro import obs, serving
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sink = (obs.RunSink.create(args.run_dir,
                                meta={"arch": args.arch, "mode": "serve",

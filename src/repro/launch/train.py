@@ -39,11 +39,12 @@ from repro.analysis.invariants import cp_seq_divisible
 from repro.configs.registry import ARCH_IDS, ModelConfig, get_config
 from repro.core import calibrate
 from repro.core import profile_cache as pcache_lib
-from repro.core.cluster import TPU_V5E_POD
+from repro.core.cluster import TPU_V5E_POD, device_peak_flops
 from repro.core.profiler_model import profile_model
 from repro.core.search import SearchEngine
 from repro.launch import mesh as mesh_lib
-from repro.core.strategy import ExecutionPlan, LayerStrategy
+from repro.launch.compile_cache import enable_compile_cache
+from repro.core.strategy import ExecutionPlan, LayerStrategy, uniform_plan
 from repro.models import build_model
 from repro.runtime import checkpoint as ckpt_lib
 from repro.runtime import resize as resize_lib
@@ -61,9 +62,25 @@ PRESET_100M = ModelConfig(
 
 def resolve_cfg(args) -> ModelConfig:
     if args.preset == "100m":
-        return PRESET_100M
-    cfg = get_config(args.arch)
-    return cfg.reduced() if args.reduced else cfg
+        cfg = PRESET_100M
+    else:
+        cfg = get_config(args.arch)
+        cfg = cfg.reduced() if args.reduced else cfg
+    if args.layers:
+        # depth cut: the first N whole layers, every width as published
+        if not 1 <= args.layers <= cfg.num_layers:
+            raise SystemExit(f"--layers {args.layers}: {cfg.name} has "
+                             f"{cfg.num_layers} layers")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def single_device_plan(cfg: ModelConfig, remat: str = "none",
+                       grad_accum: int = 1) -> ExecutionPlan:
+    """The plan a one-device run trains with (no search: nothing to shard)."""
+    return uniform_plan(cfg.name, "train", (1,), ("data",), cfg.num_layers,
+                        LayerStrategy(remat=remat),
+                        grad_accum=max(grad_accum, 1))
 
 
 def _int_list(text: str) -> list[int]:
@@ -182,15 +199,16 @@ def _emit_plan(sink, reason: str, plan: ExecutionPlan, *,
 def _aot_memory(step_fn, params, opt, batch):
     """(compiled step callable, peak HBM bytes) via the AOT memory_analysis
     the calibration path already uses — compiled once, then the compiled
-    object IS the step function (no double compile).  Falls back to the
-    plain jitted fn when the backend offers no analysis."""
-    try:
-        compiled = step_fn.lower(params, opt, batch).compile()
-        ma = compiled.memory_analysis()
-        peak = float(ma.temp_size_in_bytes + ma.argument_size_in_bytes)
-        return compiled, peak
-    except Exception:
-        return step_fn, 0.0
+    object IS the step function (no double compile).  The peak is what the
+    step holds at once: arguments + outputs + temporaries, less the bytes
+    donation aliases; 0 where the backend reports no memory analysis.  A
+    compile error propagates."""
+    compiled = step_fn.lower(params, opt, batch).compile()
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return compiled, 0.0
+    return compiled, float(ma.argument_size_in_bytes + ma.output_size_in_bytes
+                           + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
 def _run_audit(compiled_fn, step_fn, plan: ExecutionPlan, cfg: ModelConfig,
@@ -258,6 +276,11 @@ def main(argv=None):
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-1b")
     ap.add_argument("--preset", choices=["100m"], default=None)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the first N layers of the config and change "
+                         "no width (a depth cut); 0 = every layer")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="train on the first N visible devices; 0 = all")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", "--seq-len", dest="seq", type=int, default=128,
                     help="sequence length (--seq-len is an alias)")
@@ -323,6 +346,7 @@ def main(argv=None):
                          "subcommand); calibrates the search's cost model — "
                          "analytic defaults when unset")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     calibration = calibrate.DEFAULT_CALIBRATION
     if args.profile_cache:
@@ -338,8 +362,16 @@ def main(argv=None):
               f"({args.profile_cache})")
 
     cfg = resolve_cfg(args)
+    uncut = get_config(args.arch) if args.preset is None else PRESET_100M
+    print(f"arch: {cfg.name} layers {cfg.num_layers} of {uncut.num_layers}  "
+          f"d_model {cfg.d_model} heads {cfg.num_heads}/{cfg.num_kv_heads} "
+          f"head_dim {cfg.resolved_head_dim} d_ff {cfg.d_ff} "
+          f"vocab {cfg.vocab_size}")
     model = build_model(cfg)
-    n_dev = jax.device_count()
+    if not 0 <= args.devices <= jax.device_count():
+        raise SystemExit(f"--devices {args.devices}: {jax.device_count()} "
+                         "visible")
+    n_dev = args.devices or jax.device_count()
     events = _parse_events(args, n_dev)
 
     sink = (obs.RunSink.create(args.run_dir,
@@ -362,11 +394,7 @@ def main(argv=None):
         if any(n for _, n in events):
             raise SystemExit("--resize-to needs a multi-device pool "
                              "(set XLA_FLAGS=--xla_force_host_platform_device_count)")
-        strat = LayerStrategy(remat=args.remat or "none")
-        plan = ExecutionPlan(arch=cfg.name, shape="train", mesh_axes=("data",),
-                             mesh_shape=(1,), grad_accum=max(args.grad_accum, 1),
-                             layer_strategies=[strat] * cfg.num_layers,
-                             default_strategy=strat)
+        plan = single_device_plan(cfg, args.remat or "none", args.grad_accum)
         mesh = None
         rejections = None
     else:
@@ -397,7 +425,7 @@ def main(argv=None):
                 f"{n_dev} devices; interleaved needs num_layers % "
                 f"(pp*interleave) == 0, cp needs seq % (2*cp) == 0)")
         plan = res.plan
-        mesh = mesh_lib.make_mesh(shape, axes)
+        mesh = mesh_lib.make_mesh(shape, axes, devices=jax.devices()[:n_dev])
         rejections = res.rejections
     _emit_plan(sink, "search", plan,
                breakdown=_predicted_breakdown(plan, cfg, args.seq, args.batch,
@@ -485,9 +513,10 @@ def main(argv=None):
     flops_per_step = (profile_model(cfg, args.seq).model_flops_per_token()
                       * tokens_per_step)
     registry = obs.MetricsRegistry()
+    chip_peak = device_peak_flops(jax.devices()[0])   # 0 -> no MFU
     timer = obs.StepTimer(registry, tokens_per_step=tokens_per_step,
                           flops_per_step=flops_per_step,
-                          peak_flops=TPU_V5E_POD.peak_flops * devices)
+                          peak_flops=chip_peak * devices)
     drift = obs.DriftMonitor(plan.predicted_step_time)
     advisor = DriftReplanAdvisor(sink)
     drift_was_sustained = False
@@ -520,7 +549,7 @@ def main(argv=None):
                 cur_devices = new_dev                    # the old trainer stopped
                 compiled_fn = None                       # new plan recompiles
                 drift.reset(plan.predicted_step_time)    # new prediction too
-                timer.peak_flops = TPU_V5E_POD.peak_flops * plan.num_devices
+                timer.peak_flops = chip_peak * plan.num_devices
             else:
                 # legacy behavior: replan for 75% capacity and report only
                 print("!! simulated node failure: re-searching plan for 75% capacity")
@@ -530,7 +559,11 @@ def main(argv=None):
                 _emit_plan(sink, "replan-advisory", new_plan)
         batch = {k: jnp.asarray(v) for k, v in ds.batch(step).items()}
         if compiled_fn is None:
-            compiled_fn, peak_hbm = _aot_memory(step_fn, params, opt, batch)
+            with obs.span("compile") as compile_span:
+                compiled_fn, peak_hbm = _aot_memory(step_fn, params, opt, batch)
+            print(f"compiled step in {compile_span.duration_s:.1f} s, "
+                  f"peak {peak_hbm / 2**30:.2f} GiB (memory analysis)")
+            sink.emit("compile", step=step, seconds=compile_span.duration_s)
             if peak_hbm:
                 registry.gauge("peak_hbm_bytes").set(peak_hbm)
                 sink.emit("memory", step=step, peak_hbm_bytes=peak_hbm)
@@ -582,7 +615,6 @@ def main(argv=None):
               drift_sustained=drift_was_sustained,
               metrics=registry.snapshot(),
               spans=obs.default_tracer().timeline())
-    sink.close()
     if args.digest:
         canon_p, canon_o = resize_lib.canonical_state(hp, params, opt)
         p_sum = sum(float(np.abs(np.asarray(jax.device_get(x), np.float64)).sum())
@@ -590,7 +622,9 @@ def main(argv=None):
         m_sum = sum(float(np.abs(np.asarray(jax.device_get(x), np.float64)).sum())
                     for x in jax.tree.leaves(canon_o.m))
         last_loss = float(last_metrics["loss"]) if last_metrics else float("nan")
+        sink.emit("digest", params=p_sum, opt_m=m_sum, loss=last_loss)
         print(f"digest params={p_sum:.6e} opt_m={m_sum:.6e} loss={last_loss:.8f}")
+    sink.close()
     print("done")
 
 

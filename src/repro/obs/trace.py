@@ -2,10 +2,10 @@
 
 ``span("fwd")`` is a context manager that (a) records a
 :class:`SpanRecord` into an in-process timeline — name, start/end, depth,
-parent — and (b) enters a ``jax.profiler.TraceAnnotation`` (via the
-``repro.compat`` shim) so the same span shows up in a real JAX profile
-when one is being captured.  On hosts without jax the annotation degrades
-to a no-op and the Python timeline is the whole story.
+parent — and (b) enters a ``jax.profiler.TraceAnnotation`` so the same
+span shows up in a real JAX profile when one is being captured.  On hosts
+without jax the annotation degrades to a no-op and the Python timeline is
+the whole story.
 
 Spans nest per-thread: the active-span stack is thread-local, so serving
 worker threads each get a coherent parent chain.
@@ -21,15 +21,15 @@ import contextlib
 
 
 def _annotation(name: str):
-    """compat-shimmed jax.profiler.TraceAnnotation, or a nullcontext.
+    """``jax.profiler.TraceAnnotation``, or a nullcontext without jax.
 
     Imported lazily so ``repro.obs`` stays importable without jax (the
     lint lane and ``scripts/render_run.py`` both need that)."""
     try:
-        from repro import compat
-        return compat.trace_annotation(name)
-    except Exception:
+        import jax.profiler
+    except ImportError:
         return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 @dataclasses.dataclass

@@ -155,11 +155,10 @@ def lc(x, *logical_axes: str | None):
 
     Inside a partial-auto ``shard_map`` region the constraint is built on the
     mesh :func:`repro.compat.current_mesh_context` reports — the current
-    abstract mesh on new JAX (a sharding built on the outer concrete mesh
-    would be rejected there), the concrete mesh on JAX releases without the
-    abstract-mesh API.  Rule targets that are manual in the current context
-    are dropped either way (the manual axis is already fully applied by
-    shard_map itself).
+    abstract mesh (a sharding built on the outer concrete mesh would be
+    rejected there).  Rule targets that are manual in the current context
+    are dropped (the manual axis is already fully applied by shard_map
+    itself).
     """
     rules = current_rules()
     if rules is None or rules.mesh is None:

@@ -19,23 +19,21 @@ positional (global position arrays travel the ring with k/v), so the math is
 exact for any layout.  ``S % (2·cp) == 0`` is required — odd remainders are
 rejected, matching the search-side ``validate_cp`` gate.
 
-Three lowerings, mirroring :mod:`repro.parallel.pipeline`:
+Three lowerings:
 
 * **serial reference** (``mesh=None``) — the explicit-``cp``-dim loop in pure
   jnp with ``jnp.roll`` as the ring step.  This is the CPU/interpret-mode
   numerical oracle and the path the grad-equivalence tests pin.
-* **pure GSPMD** (default under a mesh, every JAX release) — same
+* **pure GSPMD** (default under a mesh) — same
   explicit-dim formulation with the leading ``cp`` dim sharding-constrained
   onto the ``cp`` mesh axis; ``jnp.roll`` on that dim lowers to the same
   collective-permute a manual ring would issue.  This also composes inside
   the pipeline's shard_map body (cp stays an auto axis there).
-* **partial-auto shard_map** (``lowering="shard_map"``, new JAX only) — the
-  ``cp`` axis is manual inside the body (``jax.lax.ppermute`` rotates
-  k/v/positions), the remaining mesh axes stay auto so DP batch sharding and
-  Megatron TP keep working inside.  Opt-in: the legacy 0.4.x shard_map
-  check-fails on partial-auto bodies (same partitioner limitation that gave
-  the pipeline its GSPMD fallback), and on-TPU it is the lowering that pins
-  the ring onto neighbor links.
+* **partial-auto shard_map** (``lowering="shard_map"``) — the ``cp`` axis is
+  manual inside the body (``jax.lax.ppermute`` rotates k/v/positions), the
+  remaining mesh axes stay auto so DP batch sharding and Megatron TP keep
+  working inside.  Opt-in: on-TPU it is the lowering that pins the ring onto
+  neighbor links.
 
 ``use_flash=True`` computes each ring step's partial with the Pallas flash
 kernel (positional masking + ``return_residuals=True``) and merges the
@@ -229,9 +227,8 @@ def _ring_shard_map(qz, kz, vz, pos, *, causal, mesh, axis, use_flash,
 
 
 def _ring_gspmd(qz, kz, vz, pos, *, causal, mesh, axis):
-    """Explicit-dim lowering for JAX releases without partial-auto shard_map:
-    the cp dim stays a real array dim, constrained onto the cp mesh axis, and
-    ``jnp.roll`` is the ring permute (same trick as the GSPMD pipeline)."""
+    """Explicit-dim lowering: the cp dim stays a real array dim, constrained
+    onto the cp mesh axis, and ``jnp.roll`` is the ring permute."""
     B, S, H, hd = qz.shape
     cp = mesh.shape[axis]
     Sc = S // cp
@@ -255,7 +252,7 @@ def ring_attention(
     cp: Optional[int] = None,      # required when mesh is None
     use_flash: bool = False,       # Pallas partials (forward-only)
     interpret: bool = False,
-    lowering: Optional[str] = None,   # None/"gspmd" | "shard_map" (new JAX)
+    lowering: Optional[str] = None,   # None/"gspmd" | "shard_map"
 ) -> jnp.ndarray:
     """Ring flash-attention over ``cp`` sequence shards; returns (B,S,H,hd).
 
@@ -271,11 +268,8 @@ def ring_attention(
         raise ValueError("ring_attention needs mesh= or cp=")
     validate_cp(S, cp)
     perm = zigzag_permutation(S, cp)
-    inv = jnp.asarray(inverse_permutation(perm))
     pos = jnp.asarray(perm, jnp.int32).reshape(cp, S // cp)
-    qz = jnp.take(q, jnp.asarray(perm), axis=1)
-    kz = jnp.take(k, jnp.asarray(perm), axis=1)
-    vz = jnp.take(v, jnp.asarray(perm), axis=1)
+    qz, kz, vz = (_permute_seq(a, perm) for a in (q, k, v))
 
     if mesh is None:
         Sc = S // cp
@@ -288,18 +282,24 @@ def ring_attention(
                                  pos, pos, causal=causal)
             out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, hd)
     elif lowering == "shard_map":
-        if not compat.HAS_TOPLEVEL_SHARD_MAP:
-            raise NotImplementedError(
-                "the shard_map ring lowering needs partial-auto shard_map "
-                "(jax.shard_map); this JAX release's legacy shard_map "
-                "check-fails on partial-auto bodies — use the default GSPMD "
-                "lowering")
         out = _ring_shard_map(qz, kz, vz, pos, causal=causal, mesh=mesh,
                               axis=axis, use_flash=use_flash,
                               interpret=interpret)
     else:
         out = _ring_gspmd(qz, kz, vz, pos, causal=causal, mesh=mesh, axis=axis)
-    return jnp.take(out, inv, axis=1)
+    return _permute_seq(out, inverse_permutation(perm))
+
+
+def _permute_seq(x, perm: np.ndarray):
+    """``x[:, perm]`` for a static permutation made of contiguous runs, as
+    slices and one concatenate.  A gather over the cp-sharded sequence dim
+    inside the pipeline's shard_map body check-fails XLA's SPMD partitioner
+    (``ExpandDeviceGroupsWithIota``); slices partition cleanly."""
+    cuts = [0] + [i for i in range(1, len(perm)) if perm[i] != perm[i - 1] + 1]
+    runs = zip(cuts, cuts[1:] + [len(perm)])
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(x, int(perm[a]), int(perm[a]) + b - a, axis=1)
+         for a, b in runs], axis=1)
 
 
 def _serial_flash_ring(qz, kz, vz, pos, causal, cp, *, interpret):

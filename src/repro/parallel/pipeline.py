@@ -1,4 +1,4 @@
-"""Schedule-aware pipeline parallelism (two lowerings, three schedules).
+"""Schedule-aware pipeline parallelism (one lowering, three schedules).
 
 The pipeline ("pod") axis is *manual*: activations move stage→stage with a
 collective permute.  The remaining mesh axes ("data", "model") stay *auto*,
@@ -7,16 +7,8 @@ Megatron TP, ZeRO) keep working — this is the TPU-native mapping of
 Galvatron's "PP outermost, across the slowest links" decision-tree take-away
 (DESIGN.md §2): cross-pod links are the slowest, PP traffic is the smallest.
 
-Two lowerings, selected by :mod:`repro.compat`:
-
-* **partial-auto shard_map** (new JAX): the pod axis is manual inside the
-  body (``jax.lax.ppermute`` moves activations), other axes stay auto.
-* **pure GSPMD** (JAX releases whose partial-auto shard_map cannot partition
-  collectives, e.g. 0.4.x on CPU): the stage dim stays *explicit*, stages
-  compute under ``jax.vmap``, the stage dim is sharding-constrained onto the
-  pod axis, and ``jnp.roll`` on the stage dim lowers to the same
-  collective-permute.  Identical schedule and math, so the two lowerings are
-  interchangeable (asserted by the pipeline-equivalence tests).
+The lowering is a partial-auto ``shard_map``: the pod axis is manual inside
+the body (``jax.lax.ppermute`` moves activations), other axes stay auto.
 
 The tick loop runs ``M + S - 1`` steps (M microbatches, S stages); jax
 autodiff reverses the schedule for the backward pass automatically (the
@@ -52,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import compat
-from repro.compat import Mesh, NamedSharding, P
+from repro.compat import Mesh, P
 
 #: Stage-boundary activation dtype.  Kept fp32: the backward pass psums the
 #: input cotangent over the pipe axis, and a bf16 all-reduce trips an XLA-CPU
@@ -71,11 +63,6 @@ def pipeline_forward(
     axis: str = "pod",
     schedule: str = "gpipe",       # gpipe | 1f1b | interleaved
     num_virtual: int = 1,          # virtual stages per physical stage (interleaved)
-    seq_axis: str | None = None,   # cp axis carrying boundary seq shards, if
-                                   # the plan's strategy actually uses cp (a
-                                   # cp=1 plan batch-shards over the cp axis
-                                   # instead — constraining seq there would
-                                   # force an unmodeled reshard per boundary)
 ) -> jnp.ndarray:
     """Returns (M, mb, seq, D) outputs of the final (virtual) stage.
 
@@ -95,24 +82,13 @@ def pipeline_forward(
         h = x_micro
         for j in range(num_virtual):
             chunk = jax.tree.map(lambda a, j=j: a[:, j], stage_params)
-            h = _forward_round(chunk, h, stage_fn, mesh=mesh, axis=axis,
-                               seq_axis=seq_axis)
+            h = _forward_round(chunk, h, stage_fn, mesh=mesh, axis=axis)
         return h
-    return _forward_round(stage_params, x_micro, stage_fn, mesh=mesh,
-                          axis=axis, seq_axis=seq_axis)
+    return _forward_round(stage_params, x_micro, stage_fn, mesh=mesh, axis=axis)
 
 
-def _forward_round(stage_params, x_micro, stage_fn, *, mesh, axis,
-                   seq_axis=None):
-    """One full traversal of the physical ring (lowering-dispatched)."""
-    if compat.HAS_TOPLEVEL_SHARD_MAP:
-        return _forward_shard_map(stage_params, x_micro, stage_fn,
-                                  mesh=mesh, axis=axis)
-    return _forward_gspmd(stage_params, x_micro, stage_fn, mesh=mesh,
-                          axis=axis, seq_axis=seq_axis)
-
-
-def _forward_shard_map(stage_params, x_micro, stage_fn, *, mesh, axis):
+def _forward_round(stage_params, x_micro, stage_fn, *, mesh, axis):
+    """One full traversal of the physical ring."""
     S = mesh.shape[axis]
     M = x_micro.shape[0]
     in_dtype = x_micro.dtype
@@ -156,50 +132,6 @@ def _forward_shard_map(stage_params, x_micro, stage_fn, *, mesh, axis):
         check_vma=False,
     )(stage_params, x_micro)
     return staged[-1]
-
-
-def _forward_gspmd(stage_params, x_micro, stage_fn, *, mesh, axis,
-                   seq_axis=None):
-    """Explicit-stage-dim lowering: vmap over stages, roll as the permute.
-
-    ``jnp.roll`` wraps the last stage's output back to stage 0 (a real
-    ppermute leaves it zero), but stage 0 only reads its recv buffer once the
-    feed window has closed — those ticks are the schedule's garbage lanes and
-    never reach ``outs``, so the wrap is harmless.
-    """
-    S = mesh.shape[axis]
-    M = x_micro.shape[0]
-    in_dtype = x_micro.dtype
-    x_micro = x_micro.astype(BOUNDARY_DTYPE)
-    # boundary blocks are (stage, mb, seq, D): stage on the pipe axis, seq on
-    # the caller's cp axis under context parallelism — each device then only
-    # holds (and permutes) a seq/cp slice of the stage boundary
-    if seq_axis is not None and (seq_axis not in mesh.axis_names
-                                 or x_micro.shape[2] % mesh.shape[seq_axis]):
-        seq_axis = None
-    stage_sharding = NamedSharding(mesh, P(axis, None, seq_axis))
-    constrain = lambda a: jax.lax.with_sharding_constraint(a, stage_sharding)
-    is_first = (jnp.arange(S) == 0)[:, None, None, None]
-
-    vstage = jax.vmap(lambda p, h: stage_fn(p, h.astype(in_dtype)).astype(BOUNDARY_DTYPE))
-
-    def tick(carry, t):
-        recv, outs = carry                      # (S, mb, seq, D) / (M, mb, seq, D)
-        mb_idx = jnp.clip(t, 0, M - 1)
-        feed = jnp.where(is_first & (t < M), 1.0, 0.0)
-        inp = feed * x_micro[mb_idx][None] + (1.0 - feed) * recv
-        h = constrain(vstage(stage_params, inp))
-        out_idx = jnp.clip(t - (S - 1), 0, M - 1)
-        write = (t >= S - 1) & (t - (S - 1) < M)
-        outs = jax.lax.dynamic_update_index_in_dim(
-            outs, jnp.where(write, h[S - 1], outs[out_idx]), out_idx, 0)
-        recv_next = constrain(jnp.roll(h, 1, axis=0))
-        return (recv_next, outs), None
-
-    outs0 = jnp.zeros_like(x_micro)
-    recv0 = constrain(jnp.zeros((S,) + x_micro.shape[1:], BOUNDARY_DTYPE))
-    (_, outs), _ = jax.lax.scan(tick, (recv0, outs0), jnp.arange(M + S - 1))
-    return outs
 
 
 def stage_stack(blocks, num_stages: int, interleave: int = 1):

@@ -204,7 +204,7 @@ class HybridParallelModel:
             k = max(plan.grad_accum, 1)
             # named_scope labels the fwd+bwd vs optimizer phases in HLO and
             # captured profiles (the in-jit counterpart of obs host spans)
-            with compat.named_scope("fwd_bwd"):
+            with jax.named_scope("fwd_bwd"):
                 if k == 1:
                     (loss, metrics), grads = jax.value_and_grad(
                         self.loss_fn, has_aux=True)(params, batch)
@@ -228,7 +228,7 @@ class HybridParallelModel:
                     loss = loss_sum / k
                     metrics = jax.tree.map(lambda m: m[-1], mets_seq)
 
-            with compat.named_scope("optimizer"):
+            with jax.named_scope("optimizer"):
                 grads = self._constrain(grads, self.grad_specs)
                 opt_state = opt_lib.AdamWState(
                     step=opt_state.step,

@@ -200,10 +200,7 @@ class PipelineTrainer:
         outs = pipeline_forward(params["blocks"], x_micro, stage_fn,
                                 mesh=self.mesh, axis=self.pipe_axis,
                                 schedule=self.schedule,
-                                num_virtual=self.interleave,
-                                # context parallelism: boundary blocks shrink
-                                # by cp — only when the plan's strategy rings
-                                seq_axis="cp" if self.strategy.cp > 1 else None)
+                                num_virtual=self.interleave)
         h = outs.reshape(B, seq, D)
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = emb_lib.lm_head(params["embed"], h, cfg)
@@ -271,9 +268,9 @@ class PipelineTrainer:
     # ------------------------------------------------------------ step
     def train_step(self, params, opt_state, batch):
         with axis_rules(self._rules):
-            with compat.named_scope("fwd_bwd"):
+            with jax.named_scope("fwd_bwd"):
                 loss, metrics, grads = self._loss_and_grads(params, batch)
-            with compat.named_scope("optimizer"):
+            with jax.named_scope("optimizer"):
                 grads = self._constrain(grads, self.grad_specs)
                 new_params, new_opt, stats = opt_lib.adamw_update(
                     params, grads, opt_state, self.opt_cfg)

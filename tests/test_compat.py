@@ -1,6 +1,5 @@
-"""Compat shim: old-JAX vs new-JAX paths of lc(), shard_map manual-axis
-bookkeeping, jit flag filtering, mesh factories — simulated on a 1-device
-mesh so both code paths run regardless of the installed JAX."""
+"""Compat module: lc() under the abstract-mesh context, shard_map manual
+axes, mesh factories and their axis types — on a 1-device mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,28 +35,9 @@ def _lc_once(mesh, monkeypatch):
     return out, built
 
 
-class _EmptyCtx:
-    empty = True
-    axis_names = ()
-    axis_types = ()
-
-
-class _FakeAxisType:
-    Manual = "manual"
-    Auto = "auto"
-
-
-def _simulate_new_jax(monkeypatch, ctx):
-    """Pretend the abstract-mesh API exists and returns ``ctx``."""
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH_API", True)
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: ctx,
-                        raising=False)
-    monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType, raising=False)
-
-
-def _simulate_old_jax(monkeypatch):
-    """Pretend the abstract-mesh API does not exist."""
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH_API", False)
+def _simulate_abstract_ctx(monkeypatch, ctx):
+    """Pretend the abstract-mesh context is ``ctx``."""
+    monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: ctx)
 
 
 # ------------------------------------------------------------ lc() paths
@@ -67,47 +47,20 @@ def test_lc_noop_outside_rules():
     np.testing.assert_array_equal(np.asarray(lc(x, "batch", "embed")), np.asarray(x))
 
 
-def test_lc_old_and_new_path_identical_shardings(monkeypatch):
-    """Old JAX (no abstract-mesh API) and new JAX (empty abstract-mesh
-    context) must constrain onto the same concrete-mesh sharding."""
-    mesh = _mesh()
-    _simulate_old_jax(monkeypatch)
-    old, old_built = _lc_once(mesh, monkeypatch)
-    _simulate_new_jax(monkeypatch, _EmptyCtx())
-    new, new_built = _lc_once(mesh, monkeypatch)
-    assert [s.spec for s in old_built] == [s.spec for s in new_built] \
-        == [P(None, "model")]
-    assert old_built[0].mesh == new_built[0].mesh
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
-
-
 def test_lc_new_path_manual_axis_dropped(monkeypatch):
-    """New-JAX path: a Manual-typed mesh axis in the abstract-mesh context
-    must be dropped from the rules (shard_map already applied it)."""
+    """A Manual-typed mesh axis in the abstract-mesh context must be dropped
+    from the rules (shard_map already applied it)."""
     mesh = _mesh()
 
     class _ManualCtx:
         empty = False
         axis_names = ("model",)
-        axis_types = (_FakeAxisType.Manual,)
+        axis_types = (compat.AxisType.Manual,)
 
-    _simulate_new_jax(monkeypatch, _ManualCtx())
+    _simulate_abstract_ctx(monkeypatch, _ManualCtx())
     out, built = _lc_once(mesh, monkeypatch)
     # every rule target was manual -> spec is empty -> lc must degrade to a
     # no-op instead of raising or constraining on the dead axis
-    assert built == []
-    np.testing.assert_array_equal(np.asarray(out), np.ones((2, 4)))
-
-
-def test_lc_old_path_manual_axis_dropped(monkeypatch):
-    """Old-JAX path: the manual set comes from compat's own shard_map
-    bookkeeping and must filter identically."""
-    mesh = _mesh()
-    _simulate_old_jax(monkeypatch)
-    with compat._manual_axes_ctx(frozenset({"model"})):
-        assert compat.tracked_manual_axes() == frozenset({"model"})
-        out, built = _lc_once(mesh, monkeypatch)
-    assert compat.tracked_manual_axes() == frozenset()
     assert built == []
     np.testing.assert_array_equal(np.asarray(out), np.ones((2, 4)))
 
@@ -116,7 +69,7 @@ def test_lc_old_path_manual_axis_dropped(monkeypatch):
 
 def test_shard_map_reports_manual_axes_inside_body():
     """current_mesh_context must see the manual axis while the body traces —
-    the invariant lc() relies on, on every JAX release."""
+    the invariant lc() relies on."""
     mesh = compat.make_mesh((1,), ("x",))
     seen = {}
 
@@ -135,7 +88,7 @@ def test_shard_map_reports_manual_axes_inside_body():
 
 
 def test_shard_map_default_axis_names_fully_manual():
-    """axis_names=None means manual over every mesh axis on both lowerings."""
+    """axis_names=None means manual over every mesh axis."""
     mesh = compat.make_mesh((1,), ("x",))
     seen = {}
 
@@ -152,12 +105,6 @@ def test_shard_map_default_axis_names_fully_manual():
 
 # ------------------------------------------------------------ jit / mesh
 
-def test_jit_drops_unknown_flags_and_none_shardings():
-    f = compat.jit(lambda x: x + 1, in_shardings=None,
-                   some_flag_no_jax_release_has=True)
-    assert float(f(jnp.float32(1.0))) == 2.0
-
-
 def test_jit_keeps_real_flags():
     f = compat.jit(lambda x, y: x + y, donate_argnums=(1,))
     assert float(f(jnp.float32(1.0), jnp.float32(2.0))) == 3.0
@@ -171,12 +118,15 @@ def test_make_mesh_and_abstract_mesh_agree():
     assert am.shape["data"] == 4 and am.shape["model"] == 2
 
 
-def test_version_probes_are_consistent():
-    assert len(compat.JAX_VERSION) == 3
-    if compat.HAS_TOPLEVEL_SHARD_MAP:
-        assert hasattr(jax, "shard_map")
-    else:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
+def test_make_mesh_axes_are_auto():
+    """``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` rejects; the runtime's meshes must be Auto,
+    with or without an explicit device subset."""
+    auto = compat.AxisType.Auto
+    assert compat.make_mesh((1,), ("data",)).axis_types == (auto,)
+    m = compat.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    assert m.axis_types == (auto, auto)
+    assert compat.abstract_mesh((4, 2), ("data", "model")).axis_types == (auto, auto)
 
 
 # ------------------------------------------------------------ cost_analysis
@@ -191,12 +141,9 @@ class _FakeComputation:
 
 def test_cost_analysis_normalizes_list_dict_and_empty():
     metrics = {"flops": 1.0, "bytes accessed": 2.0}
-    # 0.4.x: single-element list of per-program dicts
-    assert compat.cost_analysis(_FakeComputation([metrics])) == metrics
-    # newer releases: the dict directly
     assert compat.cost_analysis(_FakeComputation(dict(metrics))) == metrics
     # nothing reported
-    assert compat.cost_analysis(_FakeComputation([])) == {}
+    assert compat.cost_analysis(_FakeComputation({})) == {}
     assert compat.cost_analysis(_FakeComputation(None)) == {}
 
 

@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 from tests._prop import given, settings, st
 
-from repro import compat
 from repro.configs.registry import get_config
 from repro.core.cluster import TPU_V5E_POD
 from repro.core.decision_tree import candidate_strategies, cp_candidates
@@ -223,6 +222,7 @@ def test_elastic_replan_retains_cp_on_shrunk_mesh():
 # ---------------------------------------------------------------- multi-device
 _MP_COMMON = """
 import jax, jax.numpy as jnp, numpy as np
+from repro import compat
 from repro.configs.registry import get_config
 from repro.models import build_model
 from repro.core.strategy import LayerStrategy, ExecutionPlan
@@ -250,10 +250,11 @@ def test_ring_gspmd_lowering_matches_serial():
 
     code = """
 import jax, jax.numpy as jnp, numpy as np
+from repro import compat
 from repro.parallel.context import ring_attention
 from repro.models.attention import dense_attention
 
-mesh = jax.make_mesh((4, 2), ("cp", "data"))
+mesh = compat.make_mesh((4, 2), ("cp", "data"))
 B,S,H,hd = 2, 64, 2, 16
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q,k,v = (jax.random.normal(ks[i], (B,S,H,hd), jnp.float32) for i in range(3))
@@ -268,18 +269,16 @@ print("OK")
     assert "OK" in run_with_devices(code, n_devices=8)
 
 
-@pytest.mark.skipif(not compat.HAS_TOPLEVEL_SHARD_MAP,
-                    reason="partial-auto shard_map ring needs jax.shard_map "
-                           "(legacy shard_map check-fails on partial-auto)")
 def test_ring_shard_map_lowering_matches_serial():
     from tests._mp import run_with_devices
 
     code = """
 import jax, jax.numpy as jnp, numpy as np
+from repro import compat
 from repro.parallel.context import ring_attention
 from repro.models.attention import dense_attention
 
-mesh = jax.make_mesh((4, 2), ("cp", "data"))
+mesh = compat.make_mesh((4, 2), ("cp", "data"))
 B,S,H,hd = 2, 64, 2, 16
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q,k,v = (jax.random.normal(ks[i], (B,S,H,hd), jnp.float32) for i in range(3))
@@ -304,7 +303,7 @@ cfg = get_config(arch).reduced()
 model = build_model(cfg)
 ds = SyntheticDataset(cfg, seq_len=64, global_batch=4)
 b = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((2, 2, 2), ("cp", "data", "model"))
+mesh = compat.make_mesh((2, 2, 2), ("cp", "data", "model"))
 strat = LayerStrategy(tp=2, cp=2, zero=2)
 plan = ExecutionPlan(arch=arch, shape="t", mesh_axes=("cp","data","model"),
                      mesh_shape=(2,2,2), grad_accum=2,
@@ -321,25 +320,19 @@ print("OK", d)
     assert "OK" in run_with_devices(code, n_devices=8)
 
 
-@pytest.mark.parametrize("lowering", ["default", "gspmd"])
-def test_pipeline_with_cp_matches_single_device(lowering):
-    """PipelineTrainer on a (pod, cp, data, model) mesh: cp composes with
-    both pipeline lowerings (default = shard_map on new JAX / gspmd on old;
-    the pinned case forces the vmap+roll fallback everywhere)."""
+def test_pipeline_with_cp_matches_single_device():
+    """PipelineTrainer on a (pod, cp, data, model) mesh: cp composes with the
+    pipeline's shard_map lowering."""
     from tests._mp import run_with_devices
 
-    force = "" if lowering == "default" else """
-from repro import compat
-compat.HAS_TOPLEVEL_SHARD_MAP = False
-"""
-    code = _MP_COMMON + force + """
+    code = _MP_COMMON + """
 from repro.runtime.train_pp import PipelineTrainer
 arch = "llama3.2-1b"
 cfg = get_config(arch).reduced()
 model = build_model(cfg)
 ds = SyntheticDataset(cfg, seq_len=64, global_batch=8)
 b = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((2, 2, 2, 1), ("pod", "cp", "data", "model"))
+mesh = compat.make_mesh((2, 2, 2, 1), ("pod", "cp", "data", "model"))
 strat = LayerStrategy(cp=2, zero=1)
 plan = ExecutionPlan(arch=arch, shape="t", mesh_axes=("pod","cp","data","model"),
                      mesh_shape=(2,2,2,1), pp=2, grad_accum=4,

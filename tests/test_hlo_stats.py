@@ -185,7 +185,7 @@ def test_compiled_scan_collectives_counted_with_trips():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_stats import collective_stats
-mesh = jax.make_mesh((8,), ("x",))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 def f(x):
     def body(c, _):
         y = jax.lax.with_sharding_constraint(c @ c, NamedSharding(mesh, P("x")))

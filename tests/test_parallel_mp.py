@@ -6,6 +6,7 @@ from tests._mp import run_with_devices
 
 _COMMON = """
 import jax, jax.numpy as jnp, numpy as np
+from repro import compat
 from repro.configs.registry import get_config
 from repro.models import build_model
 from repro.core.strategy import LayerStrategy, ExecutionPlan
@@ -37,7 +38,7 @@ def test_gspmd_equivalence(arch, strat_kw):
 arch = {arch!r}
 cfg = get_config(arch).reduced()
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = compat.make_mesh((2, 4), ("data", "model"))
 strat = LayerStrategy(**{strat_kw!r})
 plan = ExecutionPlan(arch=arch, shape="t", mesh_axes=("data","model"), mesh_shape=(2,4),
                      grad_accum=2, layer_strategies=[strat]*cfg.num_layers,
@@ -64,7 +65,7 @@ from repro.runtime.train_pp import PipelineTrainer
 arch = {arch!r}
 cfg = get_config(arch).reduced()
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
 strat = LayerStrategy(tp=2, zero=1)
 plan = ExecutionPlan(arch=arch, shape="t", mesh_axes=("pod","data","model"),
                      mesh_shape=(2,2,2), pp=2, grad_accum=4,
@@ -84,24 +85,17 @@ print("OK", d)
     assert "OK" in out
 
 
-@pytest.mark.parametrize("lowering", ["shard_map", "gspmd"])
-def test_pipeline_schedule_equivalence(lowering):
+def test_pipeline_schedule_equivalence():
     """GPipe vs 1F1B vs interleaved vs the single-device reference: identical
-    losses and gradients (up to bf16 reduction-order noise) on one lowering.
-    The gspmd case pins compat.HAS_TOPLEVEL_SHARD_MAP=False so the vmap+roll
-    fallback runs even on new JAX."""
-    force = "" if lowering == "shard_map" else """
-from repro import compat
-compat.HAS_TOPLEVEL_SHARD_MAP = False
-"""
-    code = _COMMON + force + """
+    losses and gradients (up to bf16 reduction-order noise)."""
+    code = _COMMON + """
 import dataclasses
 from repro.runtime.train_pp import PipelineTrainer
 
 arch = "llama3.2-1b"
 cfg = dataclasses.replace(get_config(arch).reduced(), num_layers=4)
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
 strat = LayerStrategy(tp=2, zero=1)
 ds = SyntheticDataset(cfg, seq_len=32, global_batch=8)
 b = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
@@ -155,7 +149,7 @@ def test_pipeline_rejects_moe():
 from repro.runtime.train_pp import PipelineTrainer
 cfg = get_config("moonshot-v1-16b-a3b").reduced()
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
 strat = LayerStrategy(tp=2)
 plan = ExecutionPlan(arch="m", shape="t", mesh_axes=("pod","data","model"),
                      mesh_shape=(2,2,2), pp=2, grad_accum=4,
@@ -173,6 +167,7 @@ except NotImplementedError:
 def test_serving_sharded_decode_matches_single_device():
     code = """
 import jax, jax.numpy as jnp, numpy as np
+from repro import compat
 from repro.configs.registry import get_config
 from repro.models import build_model
 from repro.core.strategy import LayerStrategy, ExecutionPlan
@@ -180,7 +175,7 @@ from repro.runtime.serve import ServingEngine
 
 cfg = get_config("qwen2.5-3b").reduced()
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = compat.make_mesh((2, 4), ("data", "model"))
 strat = LayerStrategy(tp=4, zero=0)
 B, S = 4, 32
 plan = ExecutionPlan(arch="q", shape="t", mesh_axes=("data","model"), mesh_shape=(2,4),
