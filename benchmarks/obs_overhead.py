@@ -4,7 +4,7 @@ The observability layer (``repro.obs``) promises to be cheap enough to leave
 on for every run: the StepTimer fences on the step outputs (which the bare
 loop must also do to get honest timings — ``jax.block_until_ready`` is the
 cost of *measuring*, not of *telemetry*), and the per-step extras are pure
-host work: a trace span, a histogram/gauge update, a drift-monitor EMA, and
+host work: a trace span, the timer's arithmetic, a drift-monitor EMA, and
 one JSONL line written to the run sink.
 
 Both variants run the **same jitted train step** on the same reduced-llama
@@ -21,9 +21,9 @@ the best of ``PASSES`` paired rounds is taken.
 instrumented median is within **3%** of the bare median and that the run
 sink produced a parseable log with one ``step`` event per instrumented step.
 It also drives a tiny serving workload through ``repro.serving.build`` and
-asserts the per-request telemetry contract: one ``request_start`` /
-``first_token`` / ``request_end`` event per request in the run log, plus
-populated ``ttft_s`` / ``tpot_s`` histograms in the metrics registry.
+asserts the per-request telemetry contract: one ``request_start`` and one
+``request_end`` event per request in the run log, each end with its
+``ttft_s`` and ``tpot_s``.
 
 Usage:
   PYTHONPATH=src python benchmarks/obs_overhead.py           # table
@@ -132,8 +132,7 @@ def run() -> dict:
     rounds = []
     with tempfile.TemporaryDirectory(prefix="obs-bench-") as td:
         for p in range(PASSES):
-            registry = obs.MetricsRegistry()
-            timer = obs.StepTimer(registry, tokens_per_step=tokens,
+            timer = obs.StepTimer(tokens_per_step=tokens,
                                   flops_per_step=flops,
                                   peak_flops=device_peak_flops(jax.devices()[0]))
             drift = obs.DriftMonitor(warm)
@@ -156,8 +155,8 @@ def run() -> dict:
 
 
 def _serve_events() -> dict:
-    """Drive a few requests through the serving facade with a run sink and
-    metrics attached; return the per-request event/histogram counts."""
+    """Drive a few requests through the serving facade with a run sink
+    attached; return the per-request event counts."""
     import numpy as np
 
     from repro import obs, serving
@@ -167,11 +166,10 @@ def _serve_events() -> dict:
         arch="qwen2.5-3b", reduced=True,
         cache=serving.CacheConfig(max_context=32, page_size=8),
         scheduler=serving.SchedulerConfig(num_slots=2, prefill_chunk=8))
-    metrics = obs.MetricsRegistry()
     with tempfile.TemporaryDirectory(prefix="obs-serve-") as td:
         sink = obs.RunSink.create(pathlib.Path(td) / "serve",
                                   meta={"mode": "serve-bench"})
-        engine = serving.build(config, metrics=metrics, sink=sink)
+        engine = serving.build(config, sink=sink)
         rng = np.random.default_rng(0)
         vocab = config.model_config().vocab_size
         for _ in range(n_requests):
@@ -184,13 +182,12 @@ def _serve_events() -> dict:
     counts = {}
     for r in records:
         counts[r.get("event")] = counts.get(r.get("event"), 0) + 1
-    snap = metrics.snapshot()
+    ends = [r for r in records if r.get("event") == "request_end"]
     return {"requests": n_requests,
             "request_start": counts.get("request_start", 0),
-            "first_token": counts.get("first_token", 0),
             "request_end": counts.get("request_end", 0),
-            "ttft_observations": snap["ttft_s"]["count"],
-            "tpot_observations": snap["tpot_s"]["count"]}
+            "ttft_observations": sum("ttft_s" in r for r in ends),
+            "tpot_observations": sum("tpot_s" in r for r in ends)}
 
 
 def check(verbose: bool = True) -> dict:
@@ -207,7 +204,7 @@ def check(verbose: bool = True) -> dict:
         f"{r['bare_median_s'] * 1e3:.2f} ms vs instrumented "
         f"{r['instrumented_median_s'] * 1e3:.2f} ms per step)")
     s = _serve_events()
-    for ev in ("request_start", "first_token", "request_end",
+    for ev in ("request_start", "request_end",
                "ttft_observations", "tpot_observations"):
         assert s[ev] == s["requests"], (
             f"serving facade logged {s[ev]} {ev} for {s['requests']} "

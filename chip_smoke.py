@@ -85,12 +85,11 @@ def run_training(smoke: dict, name: str, devices: int, steps: int) -> dict:
     by: dict = {}
     for rec in obs.read_run(run_dir / "run.jsonl"):
         by.setdefault(rec["event"], []).append(rec)
-    compile_span = [s for s in by["run_end"][0]["spans"]
-                    if s["name"] == "compile"][-1]
+    compile_ev = by["compile"][0]
     return {"name": name, "devices": devices,
             "plan": by["plan"][0], "steps": by["step"],
-            "compile_s": by["compile"][0]["seconds"],
-            "compile_window": (compile_span["t0"], compile_span["t1"]),
+            "compile_s": compile_ev["seconds"],
+            "compile_window": (compile_ev["t0"], compile_ev["t1"]),
             "peak_hbm": by.get("memory", [{}])[0].get("peak_hbm_bytes", 0.0),
             "digest": by["digest"][0]}
 

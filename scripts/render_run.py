@@ -9,7 +9,7 @@ render anywhere the log file can be copied — no accelerator stack required.
 Sections: run header, step-time percentiles + tokens/sec + MFU, the plan's
 predicted comm-vs-compute split, checkpoint stalls, resize events, serving
 request percentiles (TTFT/TPOT + queue depth from the continuous-batching
-scheduler's request_start/first_token/request_end events), and the
+scheduler's request_start/request_end events), and the
 cost-model drift verdict (GALV070 signals included).
 """
 from __future__ import annotations

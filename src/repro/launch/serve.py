@@ -4,9 +4,9 @@ CPU-scale demo of the serving stack (the decode_32k / long_500k dry-run
 cells exercise the full-scale sharded path).  The CLI builds a frozen,
 statically-validated :class:`repro.serving.ServeConfig`, stands the engine
 up with ``repro.serving.build``, submits a batch of requests and drains the
-scheduler — per-request ``request_start`` / ``first_token`` / ``request_end``
-events land in the JSONL run sink (``scripts/render_run.py`` renders the
-TTFT/TPOT percentiles).
+scheduler — per-request ``request_start`` / ``request_end`` events land in
+the JSONL run sink (``scripts/render_run.py`` renders the TTFT/TPOT
+percentiles).
 
 ``serve.py search ...`` runs the serve objective instead: the search picks
 (tp, num_slots, page_size) for a cluster + context window under an SLO and
@@ -94,7 +94,6 @@ def main(argv=None):
                                meta={"arch": args.arch, "mode": "serve",
                                      "batch": args.batch})
             if args.run_dir else obs.NullSink())
-    metrics = obs.MetricsRegistry()
 
     need = args.prompt_len + args.max_new
     max_context = args.max_context or -(-need // args.page_size) * args.page_size
@@ -106,7 +105,7 @@ def main(argv=None):
             num_slots=args.num_slots or args.batch,
             prefill_chunk=args.prefill_chunk,
             temperature=args.temperature))
-    engine = serving.build(config, metrics=metrics, sink=sink)
+    engine = serving.build(config, sink=sink)
     vocab = config.model_config().vocab_size
 
     rng = np.random.default_rng(1)

@@ -164,8 +164,7 @@ def _predicted_breakdown(plan: ExecutionPlan, cfg: ModelConfig, seq_len: int,
 
 def _emit_plan(sink, reason: str, plan: ExecutionPlan, *,
                breakdown: dict | None = None,
-               spec: "resize_lib.MigrationSpec | None" = None,
-               rejections: dict | None = None) -> None:
+               spec: "resize_lib.MigrationSpec | None" = None) -> None:
     """The single "here is the active plan" emitter — one structured ``plan``
     event plus one human line, shared by the initial-search, live-resize and
     legacy-replan paths (previously three near-identical print blocks)."""
@@ -191,9 +190,6 @@ def _emit_plan(sink, reason: str, plan: ExecutionPlan, *,
     if spec is not None:
         fields["migration"] = spec.summary()
     sink.emit("plan", **fields)
-    if rejections:
-        sink.emit("search_rejections",
-                  counts={k: int(v) for k, v in rejections.items()})
 
 
 def _aot_memory(step_fn, params, opt, batch):
@@ -212,10 +208,10 @@ def _aot_memory(step_fn, params, opt, batch):
 
 
 def _run_audit(compiled_fn, step_fn, plan: ExecutionPlan, cfg: ModelConfig,
-               args, sink, params, opt, batch) -> None:
+               args, params, opt, batch) -> None:
     """Post-compile gate for the search's winning plan: audit the compiled
     step (post-SPMD HLO + staged jaxpr) against the plan before the first
-    tick, emit the ``audit`` sink event, abort on audit errors."""
+    tick, print the report, abort on audit errors."""
     from repro.analysis.hlo_audit import audit_step
 
     hlo_text = None
@@ -230,7 +226,6 @@ def _run_audit(compiled_fn, step_fn, plan: ExecutionPlan, cfg: ModelConfig,
         jaxpr = None
     report = audit_step(plan, cfg, seq_len=args.seq, global_batch=args.batch,
                         hlo_text=hlo_text, jaxpr=jaxpr)
-    sink.emit("audit", **report.to_event())
     print(report.format_table())
     if not report.ok():
         raise SystemExit("compiled-artifact audit failed: "
@@ -248,14 +243,13 @@ def _apply_resize(cfg, args, event: ElasticEvent, model, hp, plan, params, opt,
                                      profile_cache=args.profile_cache or None)
     _emit_plan(sink, "resize", new_plan, spec=spec)
     new_hp, new_mesh = _build_runtime(model, new_plan)
-    with obs.span("resize_migrate"):
-        if args.elastic_mode == "checkpoint":
-            params, opt, carry, report = resize_lib.migrate_via_checkpoint(
-                hp, new_hp, params, opt, carry, step=carry.step,
-                async_write=args.ckpt_async == "on")
-        else:
-            params, opt, carry, report = resize_lib.migrate(
-                hp, new_hp, params, opt, carry)
+    if args.elastic_mode == "checkpoint":
+        params, opt, carry, report = resize_lib.migrate_via_checkpoint(
+            hp, new_hp, params, opt, carry, step=carry.step,
+            async_write=args.ckpt_async == "on")
+    else:
+        params, opt, carry, report = resize_lib.migrate(
+            hp, new_hp, params, opt, carry)
     print(f"   {report.summary()}")
     sink.emit("resize", step=carry.step, old_devices=event.old_devices,
               new_devices=event.new_devices, reason=event.reason,
@@ -396,7 +390,6 @@ def main(argv=None):
                              "(set XLA_FLAGS=--xla_force_host_platform_device_count)")
         plan = single_device_plan(cfg, args.remat or "none", args.grad_accum)
         mesh = None
-        rejections = None
     else:
         # staged/ring run: pod axis carries the pipeline, cp axis the
         # ring-attention sequence shards; schedule/cp searched or pinned
@@ -426,11 +419,9 @@ def main(argv=None):
                 f"(pp*interleave) == 0, cp needs seq % (2*cp) == 0)")
         plan = res.plan
         mesh = mesh_lib.make_mesh(shape, axes, devices=jax.devices()[:n_dev])
-        rejections = res.rejections
     _emit_plan(sink, "search", plan,
                breakdown=_predicted_breakdown(plan, cfg, args.seq, args.batch,
-                                              calibration),
-               rejections=rejections)
+                                              calibration))
 
     if args.validate_only:
         # static verification only: nothing below this point runs — no param
@@ -512,9 +503,8 @@ def main(argv=None):
     tokens_per_step = args.batch * args.seq
     flops_per_step = (profile_model(cfg, args.seq).model_flops_per_token()
                       * tokens_per_step)
-    registry = obs.MetricsRegistry()
     chip_peak = device_peak_flops(jax.devices()[0])   # 0 -> no MFU
-    timer = obs.StepTimer(registry, tokens_per_step=tokens_per_step,
+    timer = obs.StepTimer(tokens_per_step=tokens_per_step,
                           flops_per_step=flops_per_step,
                           peak_flops=chip_peak * devices)
     drift = obs.DriftMonitor(plan.predicted_step_time)
@@ -563,14 +553,14 @@ def main(argv=None):
                 compiled_fn, peak_hbm = _aot_memory(step_fn, params, opt, batch)
             print(f"compiled step in {compile_span.duration_s:.1f} s, "
                   f"peak {peak_hbm / 2**30:.2f} GiB (memory analysis)")
-            sink.emit("compile", step=step, seconds=compile_span.duration_s)
+            sink.emit("compile", step=step, seconds=compile_span.duration_s,
+                      t0=compile_span.t0, t1=compile_span.t1)
             if peak_hbm:
-                registry.gauge("peak_hbm_bytes").set(peak_hbm)
                 sink.emit("memory", step=step, peak_hbm_bytes=peak_hbm)
             if args.audit:
                 # before the first tick (and after every resize recompile):
                 # the compiled artifact must match the plan it was ranked by
-                _run_audit(compiled_fn, step_fn, plan, cfg, args, sink,
+                _run_audit(compiled_fn, step_fn, plan, cfg, args,
                            params, opt, batch)
         timer.start()
         params, opt, metrics = compiled_fn(params, opt, batch)
@@ -609,12 +599,10 @@ def main(argv=None):
         print(f"checkpoint -> {path} "
               f"(async writer: {writer.saves_completed} saves, "
               f"{writer.blocked_seconds * 1e3:.1f} ms total step-loop stall)")
-    sink.emit("run_end", steps=timer.steps.value, tokens=tokens_done,
+    sink.emit("run_end", steps=timer.steps, tokens=tokens_done,
               wall_seconds=time.perf_counter() - t_start,
               ckpt_stall_seconds=ckpt_stall,
-              drift_sustained=drift_was_sustained,
-              metrics=registry.snapshot(),
-              spans=obs.default_tracer().timeline())
+              drift_sustained=drift_was_sustained)
     if args.digest:
         canon_p, canon_o = resize_lib.canonical_state(hp, params, opt)
         p_sum = sum(float(np.abs(np.asarray(jax.device_get(x), np.float64)).sum())

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ModelConfig
-from repro.models.common import ParamDef
+from repro.models.common import ParamDef, scoped
 from repro.models.norms import head_rmsnorm
 from repro.models.rotary import apply_rope, rope_angles
 from repro.parallel.axes import current_rules, lc, ring_context
@@ -262,6 +262,7 @@ def _out_proj(params, out, x_dtype, num_heads: int):
     return jnp.einsum("bshk,hkd->bsd", out, wo)
 
 
+@scoped("attention")
 def attention_block(
     params: dict,
     x: jnp.ndarray,                 # (B, Sq, D)

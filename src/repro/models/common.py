@@ -13,6 +13,7 @@ engine can emit.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable
 
@@ -165,6 +166,26 @@ def slice_layers(params: ParamTree, start: int, stop: int) -> ParamTree:
 
 
 Initializer = Callable[[jax.Array], ParamTree]
+
+
+#: Named scopes of the model's layers.  None is opened inside another, so
+#: every op of the step's HLO lies under at most one of them; the runtime's
+#: ``model`` scope around the loss keeps them plain path components of the
+#: backward's op names too (``transpose(jvp(model))/.../ssd/...``).
+LAYER_SCOPES = ("embed", "norm", "ssm_proj", "ssm_conv", "ssd", "attention",
+                "mlp", "head")
+
+
+def scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``.  A fresh
+    context manager is entered per call: a shared one is not re-entrant."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 def scan_or_unroll(body, carry, xs, *, unroll: bool = False, length: int | None = None):

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.configs.registry import ModelConfig
-from repro.models.common import ParamDef
+from repro.models.common import ParamDef, scoped
 from repro.parallel.axes import lc
 
 
@@ -15,11 +15,13 @@ def embed_defs(cfg: ModelConfig) -> dict:
     return defs
 
 
+@scoped("embed")
 def embed_tokens(params: dict, tokens: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:
     x = params["tok"].astype(dtype)[tokens]
     return lc(x, "batch", "seq", "embed")
 
 
+@scoped("head")
 def lm_head(params: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Returns fp32 logits (B, S, V)."""
     if cfg.tie_embeddings:

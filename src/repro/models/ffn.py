@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ModelConfig
-from repro.models.common import ParamDef
+from repro.models.common import ParamDef, scoped
 from repro.parallel.axes import lc
 
 
@@ -20,6 +20,7 @@ def ffn_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
     return defs
 
 
+@scoped("mlp")
 def ffn_apply(params: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     w_in = params["w_in"].astype(x.dtype)
     h = jnp.einsum("bsd,df->bsf", x, w_in)

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 
 from repro.configs.registry import ModelConfig
 from repro.models import embedding
-from repro.models.common import ParamDef, abstract_params, init_params, scan_or_unroll, stacked
-from repro.models.norms import rmsnorm, rmsnorm_defs
+from repro.models.common import (ParamDef, abstract_params, init_params, scan_or_unroll,
+                                 scoped, stacked)
+from repro.models.norms import gated_rmsnorm, rmsnorm, rmsnorm_defs
 from repro.models.transformer import default_layer_runner
 from repro.kernels.ssd import ops as ssd_ops
 from repro.kernels.ssd.ref import _expand_groups
@@ -75,6 +76,7 @@ def _conv_step(buf: jnp.ndarray, x_t: jnp.ndarray, w: jnp.ndarray):
     return window[:, 1:, :], y
 
 
+@scoped("ssm_proj")
 def _projections(params, h, cfg):
     dtype = h.dtype
     z = jnp.einsum("bsd,di->bsi", h, params["w_z"].astype(dtype))
@@ -82,6 +84,8 @@ def _projections(params, h, cfg):
     Bv = jnp.einsum("bsd,dg->bsg", h, params["w_B"].astype(dtype))
     Cv = jnp.einsum("bsd,dg->bsg", h, params["w_C"].astype(dtype))
     dt_raw = jnp.einsum("bsd,dh->bsh", h, params["w_dt"].astype(dtype))
+    z = lc(z, "batch", None, "ssm_inner")
+    xv = lc(xv, "batch", None, "ssm_inner")
     return z, xv, Bv, Cv, dt_raw
 
 
@@ -94,41 +98,49 @@ def mamba_block_apply(
     state: Optional[dict] = None,        # decode: {"conv_x","conv_B","conv_C","ssm"}
     impl: str = "ref",
 ):
+    """Named scopes: ``norm`` (both norms), ``ssm_proj`` (the five
+    in-projections and the out-projection), ``ssm_conv`` (the causal convs
+    and their SiLU), ``ssd`` (discretization, the scan and the D skip)."""
     d_inner, H, G, N, P = _dims(cfg)
     Bsz, S, _ = x.shape
     h = rmsnorm(params["ln"], x, cfg.norm_eps)
     z, xv, Bv, Cv, dt_raw = _projections(params, h, cfg)
-    z = lc(z, "batch", None, "ssm_inner")
-    xv = lc(xv, "batch", None, "ssm_inner")
 
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
+    with jax.named_scope("ssd"):
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + params["dt_bias"].astype(jnp.float32))
 
     new_state = None
     if mode == "decode":
-        cbx, ox = _conv_step(state["conv_x"], xv[:, 0], params["conv_x"].astype(xv.dtype))
-        cbB, oB = _conv_step(state["conv_B"], Bv[:, 0], params["conv_B"].astype(xv.dtype))
-        cbC, oC = _conv_step(state["conv_C"], Cv[:, 0], params["conv_C"].astype(xv.dtype))
-        ox, oB, oC = jax.nn.silu(ox), jax.nn.silu(oB), jax.nn.silu(oC)
-        xh = ox.reshape(Bsz, H, P).astype(jnp.float32)
-        Bt = _expand_groups(oB.reshape(Bsz, 1, G, N), H)[:, 0].astype(jnp.float32)
-        Ct = _expand_groups(oC.reshape(Bsz, 1, G, N), H)[:, 0].astype(jnp.float32)
-        ssm, y_t = ssd_ops.ssd_step(state["ssm"], xh, dt[:, 0], A, Bt, Ct)
-        y = y_t[:, None].astype(x.dtype)                            # (B,1,H,P)
-        y = y + params["D"].astype(x.dtype)[None, None, :, None] * xh[:, None].astype(x.dtype)
+        with jax.named_scope("ssm_conv"):
+            cbx, ox = _conv_step(state["conv_x"], xv[:, 0], params["conv_x"].astype(xv.dtype))
+            cbB, oB = _conv_step(state["conv_B"], Bv[:, 0], params["conv_B"].astype(xv.dtype))
+            cbC, oC = _conv_step(state["conv_C"], Cv[:, 0], params["conv_C"].astype(xv.dtype))
+            ox, oB, oC = jax.nn.silu(ox), jax.nn.silu(oB), jax.nn.silu(oC)
+        with jax.named_scope("ssd"):
+            xh = ox.reshape(Bsz, H, P).astype(jnp.float32)
+            Bt = _expand_groups(oB.reshape(Bsz, 1, G, N), H)[:, 0].astype(jnp.float32)
+            Ct = _expand_groups(oC.reshape(Bsz, 1, G, N), H)[:, 0].astype(jnp.float32)
+            ssm, y_t = ssd_ops.ssd_step(state["ssm"], xh, dt[:, 0], A, Bt, Ct)
+            y = y_t[:, None].astype(x.dtype)                        # (B,1,H,P)
+            y = y + params["D"].astype(x.dtype)[None, None, :, None] * xh[:, None].astype(x.dtype)
         new_state = {"conv_x": cbx, "conv_B": cbB, "conv_C": cbC, "ssm": ssm}
     else:
-        ox = jax.nn.silu(_causal_conv(xv, params["conv_x"].astype(xv.dtype)))
-        oB = jax.nn.silu(_causal_conv(Bv, params["conv_B"].astype(xv.dtype)))
-        oC = jax.nn.silu(_causal_conv(Cv, params["conv_C"].astype(xv.dtype)))
-        xh = ox.reshape(Bsz, S, H, P)
-        Bm = oB.reshape(Bsz, S, G, N)
-        Cm = oC.reshape(Bsz, S, G, N)
-        xh = lc(xh, "batch", None, "ssm_heads", None)
-        y, final = ssd_ops.ssd(xh.astype(jnp.float32), dt, A,
-                               Bm.astype(jnp.float32), Cm.astype(jnp.float32), impl=impl)
-        y = y.astype(x.dtype)
-        y = y + params["D"].astype(x.dtype)[None, None, :, None] * xh
+        with jax.named_scope("ssm_conv"):
+            ox = jax.nn.silu(_causal_conv(xv, params["conv_x"].astype(xv.dtype)))
+            oB = jax.nn.silu(_causal_conv(Bv, params["conv_B"].astype(xv.dtype)))
+            oC = jax.nn.silu(_causal_conv(Cv, params["conv_C"].astype(xv.dtype)))
+        with jax.named_scope("ssd"):
+            xh = ox.reshape(Bsz, S, H, P)
+            Bm = oB.reshape(Bsz, S, G, N)
+            Cm = oC.reshape(Bsz, S, G, N)
+            xh = lc(xh, "batch", None, "ssm_heads", None)
+            y, final = ssd_ops.ssd(xh.astype(jnp.float32), dt, A,
+                                   Bm.astype(jnp.float32), Cm.astype(jnp.float32), impl=impl)
+            y = y.astype(x.dtype)
+            y = y + params["D"].astype(x.dtype)[None, None, :, None] * xh
+            y = lc(y, "batch", None, "ssm_heads", None)
         if mode == "prefill":
             W = cfg.conv_width
             new_state = {
@@ -137,11 +149,11 @@ def mamba_block_apply(
                 "conv_C": Cv[:, S - (W - 1):, :],
                 "ssm": final,
             }
-        y = lc(y, "batch", None, "ssm_heads", None)
 
     y = y.reshape(Bsz, y.shape[1], d_inner)
-    y = rmsnorm(params["gate_norm"], y * jax.nn.silu(z[:, : y.shape[1]]), cfg.norm_eps)
-    out = jnp.einsum("bsi,id->bsd", y, params["w_out"].astype(x.dtype))
+    y = gated_rmsnorm(params["gate_norm"], y, z[:, : y.shape[1]], cfg.norm_eps)
+    with jax.named_scope("ssm_proj"):
+        out = jnp.einsum("bsi,id->bsd", y, params["w_out"].astype(x.dtype))
     return lc(x + out, "batch", "seq", "embed"), new_state
 
 

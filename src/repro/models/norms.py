@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ParamDef
+from repro.models.common import ParamDef, scoped
 
 
 def rmsnorm_defs(dim: int) -> dict:
@@ -29,10 +29,19 @@ def _rmsnorm(scale: jnp.ndarray, x: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (y * scale.astype(jnp.float32)).astype(dtype)
 
 
+@scoped("norm")
 def rmsnorm(params: dict, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     return _rmsnorm(params["scale"], x, eps)
 
 
+@scoped("norm")
+def gated_rmsnorm(params: dict, y: jnp.ndarray, z: jnp.ndarray,
+                  eps: float = 1e-5) -> jnp.ndarray:
+    """Mamba2's gated norm: ``rmsnorm(y * silu(z))``."""
+    return _rmsnorm(params["scale"], y * jax.nn.silu(z), eps)
+
+
 def head_rmsnorm(scale: jnp.ndarray, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
-    """qk-norm: normalize over the trailing head_dim."""
+    """qk-norm: normalize over the trailing head_dim.  Left out of the
+    ``norm`` scope: it runs inside ``attention``, and scopes do not nest."""
     return _rmsnorm(scale, x, eps)

@@ -1,15 +1,11 @@
-"""Structured runtime metrics: counters, gauges, histograms, step timing.
+"""Step timing: the step-loop instrument and its fence.
 
 Stdlib-only (the CI lint lane imports without jax/numpy): jax is touched
 lazily and only to fence (``jax.block_until_ready``) before a wall-time
-reading, so the same primitives instrument the training loop, the serving
-loop and plain host code.
-
-The unit of account is the :class:`MetricsRegistry` — a flat namespace of
-named instruments that snapshots to a JSON-serializable dict (what the
-:class:`~repro.obs.sink.RunSink` appends per step).  :class:`StepTimer`
-is the step-loop instrument: it fences on the step's outputs, records wall
-time / tokens-per-second / MFU, and hands back the record for logging.
+reading.  :class:`StepTimer` fences on the step's outputs, times the step,
+works out tokens-per-second and MFU, and hands back the
+:class:`StepRecord` that the run sink's ``step`` event and the live line
+are made from.
 """
 from __future__ import annotations
 
@@ -31,140 +27,6 @@ def fence(outputs: Any) -> None:
     jax.block_until_ready(outputs)
 
 
-class Counter:
-    """Monotonic event count."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.value = 0
-
-    def inc(self, n: int = 1) -> None:
-        self.value += n
-
-    def snapshot(self):
-        return self.value
-
-
-class Gauge:
-    """Last-written value (queue depth, MFU, EMA step time, ...)."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.value: Optional[float] = None
-
-    def set(self, value: float) -> None:
-        self.value = float(value)
-
-    def snapshot(self):
-        return self.value
-
-
-class Histogram:
-    """Value distribution with exact percentiles.
-
-    Keeps every observation (runs here are 10²-10⁴ steps — exact beats
-    bucketed at this scale, and the run report wants true p50/p99).
-    ``max_samples`` caps memory for long services: beyond it the reservoir
-    keeps a uniformly-strided subsample while count/sum stay exact."""
-
-    __slots__ = ("name", "count", "total", "min", "max", "_values",
-                 "max_samples", "_stride", "_skip")
-
-    def __init__(self, name: str, max_samples: int = 100_000):
-        self.name = name
-        self.count = 0
-        self.total = 0.0
-        self.min = float("inf")
-        self.max = float("-inf")
-        self.max_samples = max_samples
-        self._values: list[float] = []
-        self._stride = 1
-        self._skip = 0
-
-    def observe(self, value: float) -> None:
-        value = float(value)
-        self.count += 1
-        self.total += value
-        self.min = min(self.min, value)
-        self.max = max(self.max, value)
-        if self._skip:
-            self._skip -= 1
-            return
-        self._values.append(value)
-        self._skip = self._stride - 1
-        if len(self._values) >= self.max_samples:
-            # decimate: keep every other retained sample, double the stride
-            self._values = self._values[::2]
-            self._stride *= 2
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.count if self.count else float("nan")
-
-    def percentile(self, p: float) -> float:
-        """Exact (up to reservoir decimation) percentile, p in [0, 100]."""
-        if not self._values:
-            return float("nan")
-        xs = sorted(self._values)
-        if len(xs) == 1:
-            return xs[0]
-        rank = (p / 100.0) * (len(xs) - 1)
-        lo = int(rank)
-        hi = min(lo + 1, len(xs) - 1)
-        frac = rank - lo
-        return xs[lo] * (1.0 - frac) + xs[hi] * frac
-
-    def snapshot(self):
-        if not self.count:
-            return {"count": 0}
-        return {
-            "count": self.count,
-            "mean": self.mean,
-            "min": self.min,
-            "max": self.max,
-            "p50": self.percentile(50),
-            "p90": self.percentile(90),
-            "p99": self.percentile(99),
-        }
-
-
-class MetricsRegistry:
-    """Flat get-or-create namespace of instruments.
-
-    Re-requesting a name returns the same instrument; re-requesting it as a
-    different kind is a programming error and raises."""
-
-    def __init__(self):
-        self._instruments: dict[str, Any] = {}
-
-    def _get(self, name: str, cls):
-        inst = self._instruments.get(name)
-        if inst is None:
-            inst = self._instruments[name] = cls(name)
-        elif not isinstance(inst, cls):
-            raise ValueError(f"metric {name!r} already registered as "
-                             f"{type(inst).__name__}, requested {cls.__name__}")
-        return inst
-
-    def counter(self, name: str) -> Counter:
-        return self._get(name, Counter)
-
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge)
-
-    def histogram(self, name: str) -> Histogram:
-        return self._get(name, Histogram)
-
-    def snapshot(self) -> dict:
-        """JSON-serializable {name: value | histogram-stats} of everything."""
-        return {name: inst.snapshot()
-                for name, inst in sorted(self._instruments.items())}
-
-
 @dataclasses.dataclass
 class StepRecord:
     """One timed step, as handed to the sink / live formatter."""
@@ -183,9 +45,8 @@ class StepTimer:
 
     ``start()`` stamps the clock; ``stop(outputs)`` fences on the step's
     outputs (``jax.block_until_ready`` — without the fence an async backend
-    would credit the step with dispatch time only), records the step into the
-    registry's ``step_time_s`` histogram and ``tokens_per_sec``/``mfu``
-    gauges, and returns the :class:`StepRecord`.
+    would credit the step with dispatch time only), counts the step in
+    ``steps`` and returns the :class:`StepRecord`.
 
     * ``tokens_per_step`` enables tokens/sec.
     * ``flops_per_step`` (e.g. ``ModelProfile.model_flops_per_token()`` ×
@@ -194,23 +55,19 @@ class StepTimer:
     * ``clock`` is injectable for tests (fake clock).
     """
 
-    def __init__(self, registry: MetricsRegistry, *,
+    def __init__(self, *,
                  tokens_per_step: int = 0,
                  flops_per_step: float = 0.0,
                  peak_flops: float = 0.0,
                  clock: Callable[[], float] = time.perf_counter,
                  fence_fn: Callable[[Any], None] = fence):
-        self.registry = registry
         self.tokens_per_step = tokens_per_step
         self.flops_per_step = flops_per_step
         self.peak_flops = peak_flops
         self._clock = clock
         self._fence = fence_fn
         self._t0: Optional[float] = None
-        self.steps = registry.counter("steps")
-        self.hist = registry.histogram("step_time_s")
-        self.tok_gauge = registry.gauge("tokens_per_sec")
-        self.mfu_gauge = registry.gauge("mfu")
+        self.steps = 0
 
     def start(self) -> None:
         self._t0 = self._clock()
@@ -224,10 +81,7 @@ class StepTimer:
         rec = StepRecord(step=step, step_time_s=dt)
         if self.tokens_per_step:
             rec.tokens_per_sec = self.tokens_per_step / dt
-            self.tok_gauge.set(rec.tokens_per_sec)
         if self.flops_per_step and self.peak_flops:
             rec.mfu = self.flops_per_step / dt / self.peak_flops
-            self.mfu_gauge.set(rec.mfu)
-        self.steps.inc()
-        self.hist.observe(dt)
+        self.steps += 1
         return rec

@@ -16,12 +16,12 @@ Mirrors the profile-cache discipline (``repro.core.profile_cache``):
 
 Layout: ``results/runs/<run_id>/run.jsonl`` via :func:`RunSink.create`.
 Every event gets ``ts`` (wall-clock seconds, injectable clock) and
-``event`` (its type).  Event types are open-ended; the ones the repo emits
-today: ``run_start``, ``step``, ``plan``, ``ckpt``, ``resize``,
-``search_rejections``, ``drift``, ``replan_signal``, ``request``,
-``run_end``, and the serving scheduler's per-request set —
-``request_start``, ``first_token``, ``request_end``, ``request_evicted``
-(rendered as TTFT/TPOT percentiles by ``scripts/render_run.py``).
+``event`` (its type).  Event types are open-ended; each one the repo emits
+has a reader: ``run_start``, ``step``, ``plan``, ``memory``, ``ckpt``,
+``resize``, ``drift``, ``replan_signal``, ``run_end`` and the serving
+scheduler's ``request_start``, ``request_end``, ``request_evicted``
+(TTFT/TPOT percentiles) are rendered by ``scripts/render_run.py``;
+``compile`` and ``digest`` are read by ``chip_smoke.py``.
 """
 from __future__ import annotations
 

@@ -9,10 +9,18 @@ the whole story.
 
 Spans nest per-thread: the active-span stack is thread-local, so serving
 worker threads each get a coherent parent chain.
+
+Host garbage collection is recorded on the same clock: importing
+``repro.obs`` installs :func:`gc_hook` in ``gc.callbacks``, which makes
+each collection a span ``gc_gen<N>`` (N the generation collected) on the
+default tracer and, while a profiler runs, an annotation of the same name
+on the profiler's host plane.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
+import sys
 import threading
 import time
 from typing import Callable, Iterator, Optional
@@ -72,21 +80,32 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
+    def begin(self, name: str) -> SpanRecord:
+        """Open a span whose end comes as a separate call (:meth:`end`), for
+        callbacks that see a start and a stop.  Its parent is the span open
+        on this thread; it is not pushed on the stack itself.  The record is
+        appended without the lock (one ``list.append``, atomic under the
+        GIL): a garbage collection may call this while the lock is held."""
+        stack = self._stack()
+        rec = SpanRecord(name=name, t0=self._clock(), depth=len(stack),
+                         parent=stack[-1].name if stack else None)
+        self.records.append(rec)
+        return rec
+
+    def end(self, rec: SpanRecord) -> None:
+        rec.t1 = self._clock()
+
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[SpanRecord]:
+        rec = self.begin(name)
         stack = self._stack()
-        parent = stack[-1].name if stack else None
-        rec = SpanRecord(name=name, t0=self._clock(),
-                         depth=len(stack), parent=parent)
-        with self._lock:
-            self.records.append(rec)
         stack.append(rec)
         try:
             with _annotation(name):
                 yield rec
         finally:
             stack.pop()
-            rec.t1 = self._clock()
+            self.end(rec)
 
     def timeline(self) -> list[dict]:
         """JSON-serializable chronological timeline of all recorded spans."""
@@ -115,5 +134,39 @@ def default_tracer() -> Tracer:
 
 
 def span(name: str):
-    """``with obs.span("ckpt_host_copy"): ...`` on the default tracer."""
+    """``with obs.span("compile"): ...`` on the default tracer."""
     return _DEFAULT.span(name)
+
+
+# --------------------------------------------------------------------------
+# host garbage collection
+# --------------------------------------------------------------------------
+
+_GC_NAMES = tuple(f"gc_gen{g}" for g in range(3))
+_GC_OPEN: list = [None, None]   # the collection running: [record, annotation]
+
+
+def gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: one span ``gc_gen<N>`` on the default tracer
+    per collection, and a ``TraceAnnotation`` of that name while a profiler
+    runs (so the pause shows on the trace's host plane).  Without a
+    profiler it costs two clock readings and a list append.  Collections do
+    not nest, so one slot holds the open one."""
+    if phase == "start":
+        name = _GC_NAMES[info["generation"]]
+        prof = sys.modules.get("jax.profiler")
+        ann = None
+        if prof is not None and prof.TraceAnnotation.is_enabled():
+            ann = prof.TraceAnnotation(name)
+            ann.__enter__()
+        _GC_OPEN[:] = (_DEFAULT.begin(name), ann)
+    elif _GC_OPEN[0] is not None:
+        rec, ann = _GC_OPEN
+        _GC_OPEN[:] = (None, None)
+        _DEFAULT.end(rec)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
+if gc_hook not in gc.callbacks:
+    gc.callbacks.append(gc_hook)

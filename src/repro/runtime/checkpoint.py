@@ -561,18 +561,14 @@ class CheckpointWriter:
         """Queue a save.  Returns as soon as the snapshot is initiated and a
         writer slot is free — i.e. blocks only on the previous save."""
         self._raise_pending()
-        from repro.obs import span
-
         t0 = time.perf_counter()
-        with span("ckpt_host_copy"):
-            begin_host_snapshot(params, opt_state)
-            job = (pathlib.Path(directory), step,
-                   (_pin_host_leaves(params), _pin_host_leaves(opt_state)),
-                   dict(plan=plan, keep=keep, extra_meta=extra_meta,
-                        codec=codec, version=version))
+        begin_host_snapshot(params, opt_state)
+        job = (pathlib.Path(directory), step,
+               (_pin_host_leaves(params), _pin_host_leaves(opt_state)),
+               dict(plan=plan, keep=keep, extra_meta=extra_meta,
+                    codec=codec, version=version))
         self._ensure_thread()
-        with span("ckpt_enqueue"):
-            self._queue.put(job)           # blocks iff previous still pending
+        self._queue.put(job)               # blocks iff previous still pending
         self.saves_started += 1
         stalled = time.perf_counter() - t0
         self.blocked_seconds += stalled

@@ -207,16 +207,12 @@ def migrate(old_trainer, new_trainer, params, opt_state=None,
     """In-memory migration: old layout -> canonical -> new layout, entirely
     via ``device_put`` resharding (no host serialization).  Returns
     ``(params, opt_state, carry, report)`` laid out for ``new_trainer``."""
-    from repro.obs import span
-
     t0 = time.perf_counter()
     spec = diff_plans(old_trainer.plan, new_trainer.plan)
-    with span("migrate_canonicalize"):
-        canon_p, canon_o = canonical_state(old_trainer, params, opt_state)
-    with span("migrate_place"):
-        new_p = new_trainer.place_params(canon_p)
-        new_o = None if canon_o is None else new_trainer.place_opt_state(canon_o)
-        _block(new_p, new_o)
+    canon_p, canon_o = canonical_state(old_trainer, params, opt_state)
+    new_p = new_trainer.place_params(canon_p)
+    new_o = None if canon_o is None else new_trainer.place_opt_state(canon_o)
+    _block(new_p, new_o)
     new_carry = carry.carried() if carry is not None else None
     report = MigrationReport(spec=spec, seconds=time.perf_counter() - t0,
                              bytes_moved=_tree_bytes(new_p, new_o),
@@ -236,34 +232,29 @@ def migrate_via_checkpoint(old_trainer, new_trainer, params, opt_state=None,
     round trip.  Writes through the async :class:`~repro.runtime.checkpoint.
     CheckpointWriter` by default (``async_write=False`` is the synchronous
     escape hatch — byte-identical output either way)."""
-    from repro.obs import span
-
     t0 = time.perf_counter()
     spec = diff_plans(old_trainer.plan, new_trainer.plan)
-    with span("migrate_canonicalize"):
-        canon_p, canon_o = canonical_state(old_trainer, params, opt_state)
+    canon_p, canon_o = canonical_state(old_trainer, params, opt_state)
     tmp = None
     if directory is None:
         tmp = tempfile.TemporaryDirectory(prefix="resize-ckpt-")
         directory = tmp.name
     try:
-        with span("migrate_ckpt_roundtrip"):
-            if async_write:
-                with ckpt_lib.CheckpointWriter() as writer:
-                    writer.save_async(pathlib.Path(directory), step, canon_p,
-                                      canon_o, old_trainer.plan)
-                    writer.wait()
-            else:
-                ckpt_lib.save(pathlib.Path(directory), step, canon_p, canon_o,
-                              old_trainer.plan)
-            restored = ckpt_lib.restore(pathlib.Path(directory), step,
-                                        params_like=canon_p, opt_like=canon_o)
-        with span("migrate_place"):
-            new_p = new_trainer.place_params(restored["params"])
-            new_o = None
-            if canon_o is not None:
-                new_o = new_trainer.place_opt_state(restored["opt"])
-            _block(new_p, new_o)
+        if async_write:
+            with ckpt_lib.CheckpointWriter() as writer:
+                writer.save_async(pathlib.Path(directory), step, canon_p,
+                                  canon_o, old_trainer.plan)
+                writer.wait()
+        else:
+            ckpt_lib.save(pathlib.Path(directory), step, canon_p, canon_o,
+                          old_trainer.plan)
+        restored = ckpt_lib.restore(pathlib.Path(directory), step,
+                                    params_like=canon_p, opt_like=canon_o)
+        new_p = new_trainer.place_params(restored["params"])
+        new_o = None
+        if canon_o is not None:
+            new_o = new_trainer.place_opt_state(restored["opt"])
+        _block(new_p, new_o)
     finally:
         if tmp is not None:
             tmp.cleanup()

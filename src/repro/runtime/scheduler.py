@@ -28,9 +28,9 @@ Sampling is a per-request hook: ``temperature <= 0`` is greedy argmax
 with a per-request deterministic RNG.  A scheduler-level ``sample_fn``
 overrides both.
 
-Telemetry (optional): a ``repro.obs.MetricsRegistry`` receives ``ttft_s`` /
-``tpot_s`` histograms and a ``queue_depth`` gauge; a run sink receives
-``request_start`` / ``first_token`` / ``request_end`` events
+Telemetry (optional): a run sink receives ``request_start`` /
+``request_end`` / ``request_evicted`` events, each with the queue depth,
+``request_end`` with the request's TTFT and TPOT
 (``scripts/render_run.py`` renders the percentiles).
 """
 from __future__ import annotations
@@ -141,13 +141,12 @@ class ContinuousBatchingScheduler:
     def __init__(self, model: Any, params: Any, cache_cfg: PagedCacheConfig,
                  *, prefill_chunk: int = 32, dtype=jnp.bfloat16,
                  sample_fn: Optional[Callable] = None,
-                 metrics: Any = None, sink: Any = None,
+                 sink: Any = None,
                  clock: Callable[[], float] = time.perf_counter):
         self.model = model
         self.params = params
         self.cache = PagedKVCache(cache_cfg, dtype)
         self.prefill_chunk = int(prefill_chunk)
-        self.metrics = metrics
         self.sink = sink
         self._clock = clock
         self._sample = sample_fn or _default_sample
@@ -404,9 +403,6 @@ class ContinuousBatchingScheduler:
         if first:
             req.state = DECODING
             req.t_first = self._clock()
-            if self.metrics is not None:
-                self.metrics.histogram("ttft_s").observe(req.ttft_s)
-            self._emit("first_token", req, ttft_s=req.ttft_s)
         if len(req.tokens) >= req.max_new:
             self._finish(req)
 
@@ -418,10 +414,6 @@ class ContinuousBatchingScheduler:
         self._admit_order.remove(req)
         self._rngs.pop(req.rid, None)
         self._finished += 1
-        if self.metrics is not None:
-            self.metrics.histogram("tpot_s").observe(req.tpot_s)
-            self.metrics.counter("requests").inc()
-            self.metrics.counter("generated_tokens").inc(len(req.tokens))
         self._emit("request_end", req,
                    prompt_tokens=int(len(req.prompt)),
                    generated_tokens=len(req.tokens),
@@ -429,8 +421,6 @@ class ContinuousBatchingScheduler:
                    total_s=req.t_end - req.t_submit)
 
     def _emit(self, event: str, req: Request, **fields) -> None:
-        depth = len(self._queue)
-        if self.metrics is not None:
-            self.metrics.gauge("queue_depth").set(depth)
         if self.sink is not None:
-            self.sink.emit(event, rid=req.rid, queue_depth=depth, **fields)
+            self.sink.emit(event, rid=req.rid, queue_depth=len(self._queue),
+                           **fields)

@@ -30,7 +30,6 @@ class ServingEngine:
     batch: int = 0                 # request batch (for divisibility checks)
     max_len: int = 0               # cache capacity
     unroll: bool = False           # dry-run: unroll layer loops for exact FLOPs
-    metrics: Any = None            # optional repro.obs.MetricsRegistry
 
     def __post_init__(self):
         self.param_specs = shd.param_spec_tree(self.model, self.plan, self.mesh, kind="param")
@@ -139,8 +138,7 @@ class ServingEngine:
         cache_cfg = PagedCacheConfig.for_model(
             self.model.cfg, num_slots=B,
             page_size=min(16, max(S, 1)), max_context=max_len)
-        sched = ContinuousBatchingScheduler(self.model, params, cache_cfg,
-                                            metrics=self.metrics)
+        sched = ContinuousBatchingScheduler(self.model, params, cache_cfg)
         prompts = np.asarray(prompt_tokens, np.int32)
         reqs = [sched.submit(Request(prompt=prompts[b], max_new=max_new)).request
                 for b in range(B)]
@@ -151,40 +149,16 @@ class ServingEngine:
                                   max_len: int):
         """Reference generation loop (tests / oracle; not perf-critical):
         one padded ``batch × max_len`` cache, one synchronous decode step per
-        token.  The paged path must match this token-for-token.
-
-        With ``metrics`` set (a ``repro.obs.MetricsRegistry``), records the
-        request's prefill latency and per-token decode latency into the
-        ``prefill_latency_s`` / ``decode_latency_s`` histograms — the SLO
-        signals the continuous-batching scheduler batches against."""
-        import time as _time
-
-        from repro.obs import fence, span
-
+        token.  The paged path must match this token-for-token."""
         B, S = prompt_tokens.shape
         self.max_len = max_len
         self.__post_init__()
-        t0 = _time.perf_counter()
-        with span("prefill"):
-            logits, cache = self.prefill_step(params, prompt_tokens)
-            fence(logits)
-        if self.metrics is not None:
-            self.metrics.histogram("prefill_latency_s").observe(
-                _time.perf_counter() - t0)
+        logits, cache = self.prefill_step(params, prompt_tokens)
         out = [jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)]
         kv_len = jnp.full((B,), S, jnp.int32)
         for i in range(max_new - 1):
             tok = out[-1][:, None]
-            t0 = _time.perf_counter()
-            with span("decode"):
-                logits, cache = self.decode_step(
-                    params, tok, cache, jnp.int32(S + i), kv_len=kv_len + i + 1)
-                fence(logits)
-            if self.metrics is not None:
-                self.metrics.histogram("decode_latency_s").observe(
-                    _time.perf_counter() - t0)
+            logits, cache = self.decode_step(
+                params, tok, cache, jnp.int32(S + i), kv_len=kv_len + i + 1)
             out.append(jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32))
-        if self.metrics is not None:
-            self.metrics.counter("requests").inc()
-            self.metrics.counter("generated_tokens").inc(B * max_new)
         return jnp.stack(out, axis=1)

@@ -15,14 +15,19 @@ O(#layers) — essential for the 40-cell dry-run compile budget.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
+import re
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro import compat
+from repro import obs  # noqa: F401 -- importing it installs obs.gc_hook
 from repro.compat import Mesh, NamedSharding, P
 from repro.core.strategy import ExecutionPlan
+from repro.models.common import scoped
 from repro.parallel import sharding as shd
 from repro.parallel.axes import axis_rules
 from repro.parallel.remat import apply_remat
@@ -37,6 +42,7 @@ Z_LOSS_WEIGHT = 1e-4
 # loss
 # --------------------------------------------------------------------------
 
+@scoped("head")
 def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray):
     """logits (B,S,V) fp32; labels (B,S) int32, -1 = masked.  Returns
     (mean nll + z-loss, metrics dict).
@@ -54,6 +60,23 @@ def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray):
     loss = jnp.sum(nll) / denom
     zloss = Z_LOSS_WEIGHT * jnp.sum(jnp.square(lse) * valid) / denom
     return loss + zloss, {"nll": loss, "zloss": zloss, "tokens": jnp.sum(valid)}
+
+
+#: The checkout's root: source paths in op metadata are taken relative to it.
+_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def key_compile_cache_on_metadata() -> None:
+    """Make JAX's persistent compilation cache key on op metadata too.  By
+    default the key leaves metadata out, so an executable cached by a
+    program that differs from this one only in named scopes is loaded with
+    that program's op names, and a profile of this step is read with stale
+    scopes.  Source paths in the metadata are made relative to the
+    checkout, so the key stays the same when the checkout moves.  Called
+    before the train step is jitted."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(f"{_ROOT}{os.sep}"))
 
 
 # --------------------------------------------------------------------------
@@ -177,7 +200,11 @@ class HybridParallelModel:
             tree, specs)
 
     # ------------------------------------------------------------ steps
+    @scoped("model")
     def loss_fn(self, params, batch):
+        """Forward and loss under the named scope ``model``, so that the
+        model's layer scopes stay plain path components of the backward's
+        op names (``fwd_bwd/transpose(jvp(model))/.../ssd/...``)."""
         runner = make_layer_runner(self.plan, self.mesh, unroll=self.unroll)
         kwargs = {}
         if "vis_embeds" in batch:
@@ -250,6 +277,7 @@ class HybridParallelModel:
 
     def jit_train_step(self, donate: bool = True):
         """jit with explicit in/out shardings (None mesh -> plain jit)."""
+        key_compile_cache_on_metadata()
         if self.mesh is None:
             return compat.jit(self.train_step, donate_argnums=(0, 1) if donate else ())
         ps = self.shardings(self.param_specs)

@@ -43,8 +43,9 @@ from repro.parallel.pipeline import pipeline_forward, stage_stack, unstage_stack
 from repro.parallel.remat import apply_remat
 from repro.runtime import checkpoint as ckpt_lib
 from repro.runtime import optimizer as opt_lib
-from repro.runtime.train import softmax_xent
+from repro.runtime.train import key_compile_cache_on_metadata, softmax_xent
 from repro.models import embedding as emb_lib
+from repro.models.common import scoped
 from repro.models.norms import rmsnorm
 
 
@@ -174,8 +175,10 @@ class PipelineTrainer:
             return M // S
         return 1
 
+    @scoped("model")
     def _forward_loss(self, params, tokens, labels, vis_embeds, n_micro):
-        """Loss over one batch slice run as ``n_micro`` pipeline microbatches."""
+        """Loss over one batch slice run as ``n_micro`` pipeline microbatches,
+        under the named scope ``model`` (see ``HybridParallelModel.loss_fn``)."""
         model, cfg = self.model, self.model.cfg
         B = tokens.shape[0]
         mb = B // n_micro
@@ -281,6 +284,7 @@ class PipelineTrainer:
         return new_params, new_opt, metrics
 
     def jit_train_step(self, donate: bool = True):
+        key_compile_cache_on_metadata()
         ps = self.shardings(self.param_specs)
         os_ = opt_lib.AdamWState(
             step=NamedSharding(self.mesh, P()),

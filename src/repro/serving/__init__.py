@@ -184,16 +184,15 @@ class ServeSession:
 
 
 def build(config: ServeConfig, *, model: Any = None, params: Any = None,
-          metrics: Any = None, sink: Any = None,
+          sink: Any = None,
           sample_fn: Optional[Callable] = None,
           clock: Optional[Callable[[], float]] = None) -> ServeSession:
     """Stand up a :class:`ServeSession` from a validated :class:`ServeConfig`.
 
     ``model`` / ``params`` default to a fresh ``build_model`` +
     ``init(PRNGKey(config.init_seed))`` in the serving dtype; pass trained
-    params to serve real weights.  ``metrics`` (a MetricsRegistry) and
-    ``sink`` (a RunSink) wire the TTFT/TPOT histograms and the per-request
-    JSONL events."""
+    params to serve real weights.  ``sink`` (a RunSink) receives the
+    per-request JSONL events."""
     import jax
 
     from repro.models import build_model
@@ -215,12 +214,12 @@ def build(config: ServeConfig, *, model: Any = None, params: Any = None,
     scheduler = ContinuousBatchingScheduler(
         model, params, config.cache_config(),
         prefill_chunk=config.scheduler.prefill_chunk,
-        sample_fn=sample_fn, metrics=metrics, sink=sink, **kw)
+        sample_fn=sample_fn, sink=sink, **kw)
     return ServeSession(config, scheduler, model, params)
 
 
 def step_engine(model: Any, plan: ExecutionPlan, mesh=None, *, batch: int = 0,
-                max_len: int = 0, unroll: bool = False, metrics: Any = None):
+                max_len: int = 0, unroll: bool = False):
     """The sanctioned constructor for the step-level ``ServingEngine``
     (mesh-sharded prefill/decode, dry-run lowering).  Direct
     ``ServingEngine(...)`` construction outside ``repro.serving`` is
@@ -228,4 +227,4 @@ def step_engine(model: Any, plan: ExecutionPlan, mesh=None, *, batch: int = 0,
     from repro.runtime.serve import ServingEngine
 
     return ServingEngine(model, plan, mesh, batch=batch, max_len=max_len,
-                         unroll=unroll, metrics=metrics)
+                         unroll=unroll)

@@ -1,5 +1,5 @@
-"""Runtime telemetry subsystem: metrics/step timing, nested trace spans,
-the JSONL run sink (schema + crash tolerance), cost-model drift detection,
+"""Runtime telemetry subsystem: step timing, nested trace spans, host
+garbage-collection spans, the JSONL run sink (schema + crash tolerance), cost-model drift detection,
 the drift->replan advisory signal, and the end-to-end driver run log that
 ``scripts/render_run.py`` renders."""
 import json
@@ -33,48 +33,10 @@ class FakeClock:
 
 # ---------------------------------------------------------------- metrics
 
-def test_histogram_exact_percentiles_and_snapshot():
-    h = obs.Histogram("t")
-    for v in [1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0, 9.0, 10.0]:
-        h.observe(v)
-    assert h.percentile(0) == 1.0 and h.percentile(100) == 10.0
-    assert h.percentile(50) == pytest.approx(5.5)
-    snap = h.snapshot()
-    assert snap["count"] == 10 and snap["mean"] == pytest.approx(5.5)
-    assert snap["p99"] == pytest.approx(9.91)
-    assert obs.Histogram("empty").snapshot() == {"count": 0}
-
-
-def test_histogram_reservoir_keeps_exact_count_and_extremes():
-    h = obs.Histogram("t", max_samples=64)
-    n = 1000
-    for i in range(n):
-        h.observe(float(i))
-    assert h.count == n and h.total == pytest.approx(sum(range(n)))
-    assert h.min == 0.0 and h.max == float(n - 1)
-    assert len(h._values) < n                     # decimated...
-    assert h.percentile(50) == pytest.approx(n / 2, rel=0.15)  # ...still sane
-
-
-def test_registry_get_or_create_and_kind_mismatch():
-    reg = obs.MetricsRegistry()
-    c = reg.counter("steps")
-    c.inc()
-    assert reg.counter("steps") is c and c.value == 1
-    reg.gauge("mfu").set(0.4)
-    reg.histogram("dt").observe(0.1)
-    with pytest.raises(ValueError, match="already registered"):
-        reg.gauge("steps")
-    snap = reg.snapshot()
-    assert snap["steps"] == 1 and snap["mfu"] == 0.4
-    assert snap["dt"]["count"] == 1
-
-
 def test_step_timer_fences_and_computes_rates():
     clock = FakeClock(tick=0.0)
     fenced = []
-    reg = obs.MetricsRegistry()
-    timer = obs.StepTimer(reg, tokens_per_step=1000, flops_per_step=4e12,
+    timer = obs.StepTimer(tokens_per_step=1000, flops_per_step=4e12,
                           peak_flops=1e14, clock=clock,
                           fence_fn=fenced.append)
     timer.start()
@@ -84,7 +46,7 @@ def test_step_timer_fences_and_computes_rates():
     assert rec.step == 7 and rec.step_time_s == pytest.approx(0.5)
     assert rec.tokens_per_sec == pytest.approx(2000.0)
     assert rec.mfu == pytest.approx(4e12 / 0.5 / 1e14)
-    assert reg.counter("steps").value == 1
+    assert timer.steps == 1
     assert rec.as_dict()["mfu"] == rec.mfu
     with pytest.raises(RuntimeError):
         timer.stop(8)                             # stop without start
@@ -134,6 +96,61 @@ def test_module_level_span_uses_default_tracer():
     with obs.span("unit-test-span"):
         pass
     assert any(r["name"] == "unit-test-span" for r in tr.timeline()[before:])
+
+
+def test_begin_end_pair_for_callbacks():
+    """A callback's span: opened and closed by two calls, parented to the
+    span open on the thread, and not itself a parent of later spans."""
+    tr = obs.Tracer(clock=FakeClock())
+    with tr.span("step"):
+        rec = tr.begin("cb")
+        with tr.span("inner"):
+            pass
+        assert rec.t1 is None
+        tr.end(rec)
+    by = {r["name"]: r for r in tr.timeline()}
+    assert by["cb"]["parent"] == "step" and by["cb"]["depth"] == 1
+    assert by["inner"]["parent"] == "step"
+    assert by["cb"]["t0"] < by["inner"]["t0"] < by["cb"]["t1"]
+    assert tr.total("cb") == by["cb"]["t1"] - by["cb"]["t0"]
+
+
+# -------------------------------------------------------------- gc spans
+
+def test_gc_collect_leaves_closed_gc_gen2_span():
+    import gc
+
+    assert obs.gc_hook in gc.callbacks          # installed on import
+    tr = obs.default_tracer()
+    before = len(tr.records)
+    with obs.span("window"):
+        gc.collect()
+    new = tr.records[before:]
+    gen2 = [r for r in new if r.name == "gc_gen2"]
+    assert gen2 and all(r.t1 is not None and r.t1 >= r.t0 for r in gen2)
+    window = next(r for r in new if r.name == "window")
+    assert gen2[-1].parent == "window"
+    assert window.t0 <= gen2[-1].t0 <= gen2[-1].t1 <= window.t1
+
+
+def test_gc_span_annotates_the_profiler_host_plane(tmp_path):
+    """While a profiler runs, a collection is also an annotation of the same
+    name on the trace's host plane, on the device trace's clock."""
+    import gc
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(pb)).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert "gc_gen2" in names
 
 
 # ------------------------------------------------------------------- sink
@@ -346,7 +363,10 @@ def test_driver_emits_valid_run_log(run_log):
         assert "loss" in s and "grad_norm" in s and s["mfu"] >= 0
     end = by["run_end"][0]
     assert end["steps"] == 4 and end["tokens"] == 4 * 4 * 32
-    assert end["metrics"]["step_time_s"]["count"] == 4
+    compile_ev = by["compile"][0]
+    assert compile_ev["t0"] < compile_ev["t1"]
+    assert compile_ev["seconds"] == pytest.approx(
+        compile_ev["t1"] - compile_ev["t0"])
     assert "ckpt_stall_seconds" in end and end["drift_sustained"] is False
 
 
