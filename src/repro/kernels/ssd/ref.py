@@ -6,6 +6,9 @@ Semantics (per batch b, head h, state n, channel p):
     y_t[p] = sum_n C_t[n] * S_t[n, p]
 
 Heads are grouped: head h reads B/C from group ``h // (H // G)``.
+``ssd_chunked`` keeps B and C at group width (B, S, G, N) and lets heads
+read them through the split H = G × R, as the Pallas kernel's index map
+does, so no per-head copy of B or C is ever materialized.
 
 Two references are provided: ``ssd_naive`` (step-by-step lax.scan — the
 ground truth) and ``ssd_chunked`` (the blocked SSD algorithm the Pallas
@@ -67,42 +70,50 @@ def ssd_chunked(x, dt, A, B, C, chunk: int = 64, initial_state=None):
 
     Shapes as in ``ssd_naive``; S must be divisible by ``chunk``.
     Returns (y (B,S,H,P), final_state (B,H,N,P)).
+
+    B and C stay at group width (B,S,G,N) throughout: the head axis is split
+    as H = G × R (head h = g·R + r reads group g), so each group's C·B
+    product, chunk-state input and inter-chunk read of C is computed once and
+    broadcast over its R heads, never copied to H heads.
     """
     Bsz, S, H, P = x.shape
-    N = B.shape[-1]
+    G, N = B.shape[-2:]
+    R = H // G
     assert S % chunk == 0, (S, chunk)
     nc, Q = S // chunk, chunk
 
-    xc = x.reshape(Bsz, nc, Q, H, P).astype(jnp.float32)
-    dtc = dt.reshape(Bsz, nc, Q, H).astype(jnp.float32)
-    Bh = _expand_groups(B, H).reshape(Bsz, nc, Q, H, N).astype(jnp.float32)
-    Ch = _expand_groups(C, H).reshape(Bsz, nc, Q, H, N).astype(jnp.float32)
+    xc = x.reshape(Bsz, nc, Q, G, R, P).astype(jnp.float32)
+    dtc = dt.reshape(Bsz, nc, Q, G, R).astype(jnp.float32)
+    Bc = B.reshape(Bsz, nc, Q, G, N).astype(jnp.float32)
+    Cc = C.reshape(Bsz, nc, Q, G, N).astype(jnp.float32)
 
-    da = dtc * A[None, None, None, :]                               # (B,nc,Q,H)
-    cum = jnp.cumsum(da, axis=2)                                    # (B,nc,Q,H)
-    total = cum[:, :, -1, :]                                        # (B,nc,H)
+    da = dtc * A.reshape(G, R)                                      # (B,nc,Q,G,R)
+    cum = jnp.cumsum(da, axis=2)                                    # (B,nc,Q,G,R)
+    total = cum[:, :, -1]                                           # (B,nc,G,R)
 
     # ---- intra-chunk (the "dual" quadratic form, masked by decay) -------
-    L = _segsum(jnp.moveaxis(da, 2, -1))                            # (B,nc,H,Q,Q)
-    CB = jnp.einsum("bcihn,bcjhn->bchij", Ch, Bh)
-    M = CB * jnp.exp(L)
-    M = M * jnp.moveaxis(dtc, 2, -1)[:, :, :, None, :]              # × dt_j
-    y_intra = jnp.einsum("bchij,bcjhp->bcihp", M, xc)
+    L = _segsum(jnp.moveaxis(da, 2, -1))                            # (B,nc,G,R,Q,Q)
+    CB = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc)                   # once per group
+    M = CB[:, :, :, None] * jnp.exp(L)
+    M = M * jnp.moveaxis(dtc, 2, -1)[..., None, :]                  # × dt_j
+    y_intra = jnp.einsum("bcgrij,bcjgrp->bcigrp", M, xc)
 
     # ---- chunk state contributions ----------------------------------------
-    w = jnp.exp(total[:, :, None, :] - cum) * dtc                   # (B,nc,Q,H)
-    contrib = jnp.einsum("bcjhn,bcjh,bcjhp->bchnp", Bh, w, xc)      # (B,nc,H,N,P)
+    w = jnp.exp(total[:, :, None] - cum) * dtc                      # (B,nc,Q,G,R)
+    contrib = jnp.einsum("bcjgn,bcjgr,bcjgrp->bcgrnp", Bc, w, xc)   # (B,nc,G,R,N,P)
 
     # ---- inter-chunk recurrence -------------------------------------------
-    state0 = initial_state if initial_state is not None else jnp.zeros((Bsz, H, N, P), jnp.float32)
-    decay_chunk = jnp.exp(total)                                    # (B,nc,H)
+    state0 = (initial_state.reshape(Bsz, G, R, N, P) if initial_state is not None
+              else jnp.zeros((Bsz, G, R, N, P), jnp.float32))
+    decay_chunk = jnp.exp(total)                                    # (B,nc,G,R)
 
     def body(state, c):
-        y_off = jnp.einsum("bihn,bhnp->bihp", Ch[:, c] * jnp.exp(cum[:, c])[..., None], state)
-        new_state = decay_chunk[:, c][:, :, None, None] * state + contrib[:, c]
+        y_off = (jnp.exp(cum[:, c])[..., None]
+                 * jnp.einsum("bign,bgrnp->bigrp", Cc[:, c], state))
+        new_state = decay_chunk[:, c][..., None, None] * state + contrib[:, c]
         return new_state, y_off
 
     final, y_inter = jax.lax.scan(body, state0, jnp.arange(nc))
-    y_inter = jnp.moveaxis(y_inter, 0, 1)                           # (B,nc,Q,H,P)
+    y_inter = jnp.moveaxis(y_inter, 0, 1)                           # (B,nc,Q,G,R,P)
     y = (y_intra + y_inter).reshape(Bsz, S, H, P)
-    return y.astype(x.dtype), final
+    return y.astype(x.dtype), final.reshape(Bsz, H, N, P)
