@@ -1,4 +1,4 @@
-"""qwen3-14b — dense, GQA kv=8, qk-norm.  [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-14b — dense, GQA kv=8, qk-norm.  [hf:Qwen/Qwen3-14B; hf]"""
 from repro.configs.registry import ModelConfig
 
 CONFIG = ModelConfig(
@@ -14,5 +14,6 @@ CONFIG = ModelConfig(
     qk_norm=True,
     mlp_type="swiglu",
     rope_theta=1_000_000.0,
-    source="hf:Qwen/Qwen3-8B; hf",
+    norm_eps=1e-6,
+    source="hf:Qwen/Qwen3-14B; hf",
 )
