@@ -98,7 +98,8 @@ class EncDecLM:
 
     # ------------------------------------------------------------ train
     def forward_train(self, params, tokens, *, frames=None, vis_embeds=None,
-                      layer_runner=None, dtype=jnp.bfloat16, unroll: bool = False):
+                      layer_runner=None, dtype=jnp.bfloat16, unroll: bool = False,
+                      zero_head=None):
         """tokens: (B, S) decoder input; frames: (B, F, D) stub encoder input."""
         cfg = self.cfg
         if frames is None:  # smoke-test convenience: derive stub frames from zeros
@@ -112,7 +113,7 @@ class EncDecLM:
 
         x, _ = scan_or_unroll(body, x, params["dec_blocks"], unroll=unroll)
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return embedding.lm_head(params["embed"], x, cfg), jnp.float32(0.0)
+        return embedding.lm_head(params["embed"], x, cfg, zero_head), jnp.float32(0.0)
 
     # ------------------------------------------------------------ serving
     def init_cache(self, batch, max_len, dtype=jnp.bfloat16):

@@ -74,7 +74,7 @@ class HybridLM(Mamba2LM):
 
     # ------------------------------------------------------------ train
     def forward_train(self, params, tokens, *, vis_embeds=None, layer_runner=None,
-                      dtype=jnp.bfloat16, unroll: bool = False):
+                      dtype=jnp.bfloat16, unroll: bool = False, zero_head=None):
         cfg = self.cfg
         x = embedding.embed_tokens(params["embed"], tokens, dtype)
         seg_params, tail_params = self._split_stacks(params["blocks"])
@@ -95,7 +95,7 @@ class HybridLM(Mamba2LM):
         if self.remainder:
             x = mamba_scan(x, tail_params)
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return embedding.lm_head(params["embed"], x, cfg), jnp.float32(0.0)
+        return embedding.lm_head(params["embed"], x, cfg, zero_head), jnp.float32(0.0)
 
     # ------------------------------------------------------------ serving
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -192,7 +192,7 @@ class Mamba2LM:
 
     # ------------------------------------------------------------ train
     def forward_train(self, params, tokens, *, vis_embeds=None, layer_runner=None,
-                      dtype=jnp.bfloat16):
+                      dtype=jnp.bfloat16, zero_head=None):
         runner = layer_runner or default_layer_runner
         x = embedding.embed_tokens(params["embed"], tokens, dtype)
 
@@ -202,7 +202,7 @@ class Mamba2LM:
 
         x, extra = runner(params["blocks"], x, apply_block)
         x = rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
-        return embedding.lm_head(params["embed"], x, self.cfg), extra
+        return embedding.lm_head(params["embed"], x, self.cfg, zero_head), extra
 
     # ------------------------------------------------------------ serving
     def _state_shapes(self, batch: int):

@@ -25,6 +25,7 @@ from repro.models.common import (
 )
 from repro.models.norms import rmsnorm, rmsnorm_defs
 from repro.parallel.axes import lc
+from repro.parallel.zero_head import ZeroHead
 
 # layer_runner(stacked_block_params, x, apply_block) -> x
 LayerRunner = Callable
@@ -126,6 +127,7 @@ class DenseTransformerLM:
         vis_embeds: Optional[jnp.ndarray] = None,  # (B, Sv, D) stub frontend
         layer_runner: Optional[LayerRunner] = None,
         dtype=jnp.bfloat16,
+        zero_head: Optional[ZeroHead] = None,   # the head weight's ZeRO-3 layout
     ):
         """Returns (logits fp32 (B, S_total, V), extra fp32 scalar)."""
         runner = layer_runner or default_layer_runner
@@ -137,7 +139,7 @@ class DenseTransformerLM:
 
         x, extra = runner(params["blocks"], x, apply_block)
         x = rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
-        return embedding.lm_head(params["embed"], x, self.cfg), extra
+        return embedding.lm_head(params["embed"], x, self.cfg, zero_head), extra
 
     # ------------------------------------------------------------ serving
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):

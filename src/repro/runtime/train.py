@@ -31,6 +31,7 @@ from repro.models.common import scoped
 from repro.parallel import sharding as shd
 from repro.parallel.axes import axis_rules
 from repro.parallel.remat import apply_remat
+from repro.parallel import zero_head as zh_lib
 from repro.runtime import checkpoint as ckpt_lib
 from repro.runtime import optimizer as opt_lib
 
@@ -131,6 +132,7 @@ class HybridParallelModel:
     grad_specs: Any = None
     opt_specs: Any = None
     batch_spec: Any = None
+    zero_head: Optional[zh_lib.ZeroHead] = None   # the head weight's ZeRO-3 layout
 
     # ------------------------------------------------------------ params
     @property
@@ -213,6 +215,8 @@ class HybridParallelModel:
             kwargs["frames"] = batch["frames"]
         if self.unroll and not self._supports_grouping:
             kwargs["unroll"] = True
+        if self.zero_head is not None:
+            kwargs["zero_head"] = self.zero_head
         logits, extra = self.model.forward_train(
             params, batch["tokens"], layer_runner=runner, **kwargs)
         off = self.model.text_offset()
@@ -305,5 +309,6 @@ def construct_hybrid_parallel_model(
     hp.param_specs = shd.param_spec_tree(model, plan, mesh, kind="param")
     hp.grad_specs = shd.param_spec_tree(model, plan, mesh, kind="grad")
     hp.opt_specs = shd.param_spec_tree(model, plan, mesh, kind="opt")
+    hp.zero_head = zh_lib.zero_head(hp.param_specs, mesh)
     hp.batch_spec = shd.batch_spec(plan)
     return hp
