@@ -34,12 +34,12 @@ def _analytic_step_flops(cfg, spec, plan: dict, *, causal_frac: float = 1.0) -> 
     """Global FLOPs per step as the runtime executes it (baseline runtime
     computes the full S² grid => causal_frac=1.0; the causal-skip §Perf
     variant passes the triangular fraction)."""
+    from repro.configs.arch import KV_KINDS, layer_slots
     from repro.core.profiler_model import profile_model
 
     samples = spec.global_batch
     if spec.kind == "train":
-        prof = profile_model(cfg, spec.seq_len +
-                             (0 if cfg.family != "vlm" else 0), causal_frac=causal_frac)
+        prof = profile_model(cfg, spec.seq_len, causal_frac=causal_frac)
         # strategy mix (remat recompute factors) from the plan summary
         mix = plan.get("strategies", {})
         total_layers = max(sum(mix.values()), 1)
@@ -62,12 +62,9 @@ def _analytic_step_flops(cfg, spec, plan: dict, *, causal_frac: float = 1.0) -> 
     # decode: one token against a cache of seq_len
     prof = profile_model(cfg, 1, causal_frac=1.0)
     per_tok = sum(lp.flops for lp in prof.layers) + prof.head_flops
-    if not cfg.is_attention_free:
-        S = spec.seq_len
-        hd = cfg.resolved_head_dim
-        attn_layers = (cfg.num_layers if cfg.family != "hybrid"
-                       else cfg.num_layers // cfg.attn_every)
-        per_tok += attn_layers * 4.0 * S * cfg.num_heads * hd
+    # k/v reads of every layer that keeps a decode cache
+    attn_layers = sum(s.kind in KV_KINDS for s in layer_slots(cfg))
+    per_tok += attn_layers * 4.0 * spec.seq_len * cfg.num_heads * cfg.resolved_head_dim
     return samples * per_tok
 
 

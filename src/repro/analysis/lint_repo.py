@@ -34,6 +34,9 @@ Rules:
   ``repro/serving`` (and the class's own module): the supported serving
   surface is the validated ``ServeConfig`` + ``repro.serving.build`` facade;
   step-level access goes through ``repro.serving.step_engine``.
+* **family-read** — ``src/`` reads a config's ``family`` attribute only in
+  ``repro/configs/arch.py``; the rest asks ``arch_of(cfg)`` or the layer
+  kinds of ``layer_slots(cfg)``, so a new architecture is one row there.
 * **galv-catalog** — repo-level (not per-file): every ``GALV###`` code
   referenced by the verifier/auditor sources (``plan_check.py``,
   ``hlo_audit.py``, ``jaxpr_audit.py``) must appear in the ``plan_check``
@@ -90,14 +93,16 @@ class LintViolation:
 def _rules_for(rel: pathlib.PurePosixPath) -> frozenset[str]:
     """Which rules apply to one repo-relative file."""
     parts = rel.parts
+    family = ({"family-read"} if parts[:1] == ("src",)
+              and str(rel) != "src/repro/configs/arch.py" else set())
     if str(rel) == "src/repro/compat.py":
-        return frozenset({"hypothesis-shim", "paramdef-scale"})
+        return frozenset({"hypothesis-shim", "paramdef-scale"} | family)
     if parts and parts[0] == "tests":
         if str(rel) == "tests/_prop.py":
             return frozenset()
         return frozenset({"hypothesis-shim"})
     rules = frozenset(COMPAT_RULES) | {"hypothesis-shim", "paramdef-scale",
-                                       "serve-config"}
+                                       "serve-config"} | family
     if str(rel) in CALIBRATION_SCOPED_FILES:
         rules = rules | {"calibration-constant"}
     if parts[:3] == ("src", "repro", "runtime"):
@@ -192,6 +197,10 @@ class _Visitor(ast.NodeVisitor):
 
     # ---------------------------------------------------------- uses
     def visit_Attribute(self, node: ast.Attribute) -> None:
+        if node.attr == "family" and isinstance(node.ctx, ast.Load):
+            self._flag(node, "family-read",
+                       "family attribute read outside repro/configs/arch.py — ask "
+                       "arch_of(cfg) or the layer kinds of layer_slots(cfg)")
         if (isinstance(node.value, ast.Name)
                 and node.value.id in self.jax_aliases):
             if node.attr == "jit":

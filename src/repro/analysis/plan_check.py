@@ -27,7 +27,7 @@ code  slug                      invariant
 015   pp-schedule-unrealizable  1f1b windowable / interleave divides
 020   inflight-hbm-overcommit   schedule-aware peak memory <= HBM
 030   cp-ring-inconsistent      one uniform cp degree across layers
-031   cp-family-unsupported     ring attention is dense-family only
+031   cp-family-unsupported     cp on an architecture without ring attention
 032   cp-axis-mismatch          cp>1 needs a "cp" axis of width cp
 040   pp-boundary-dtype-mismatch cost-model bytes/elem == runtime dtype
 050   ckpt-plan-incompatible    checkpoint arch/layout matches new plan
@@ -71,6 +71,7 @@ import dataclasses
 from typing import Optional
 
 from repro.analysis import invariants as inv
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig
 from repro.core.cluster import ClusterSpec
 from repro.core.dynamic_programming import (interleave_realizable,
@@ -114,7 +115,7 @@ CATALOG: dict[str, tuple[str, str, str]] = {
                 "use one uniform cp degree: mixed ring sizes give layers "
                 "inconsistent ppermute orderings over the cp axis"),
     "GALV031": ("cp-family-unsupported", ERROR,
-                "ring attention is implemented for dense-family models only"),
+                "use cp=1: this architecture has no ring attention (Arch.cp)"),
     "GALV032": ("cp-axis-mismatch", ERROR,
                 "cp>1 plans need a 'cp' mesh axis of exactly that width"),
     "GALV040": ("pp-boundary-dtype-mismatch", ERROR,
@@ -318,7 +319,7 @@ def check_strategy(s: LayerStrategy, *, stage_devices: int, micro_batch: int,
         return "GALV001"
     if s.ep > 1 and not inv.experts_shardable(cfg.num_experts, s.ep, dp):
         return "GALV006"
-    if s.cp > 1 and cfg.family != "dense":
+    if s.cp > 1 and not arch_of(cfg).cp:
         return "GALV031"
     if not inv.cp_seq_divisible(seq_len, s.cp):
         return "GALV010"
@@ -473,9 +474,9 @@ def check_plan(
         if micro is not None and ok and not inv.batch_shardable(micro, dp):
             diag(Diagnostic("GALV012", f"microbatch {micro} does not shard "
                             f"over dp={dp}", where=where))
-        if s.cp > 1 and cfg.family != "dense":
-            diag(Diagnostic("GALV031", f"cp={s.cp} on family "
-                            f"{cfg.family!r}", where=where))
+        if s.cp > 1 and not arch_of(cfg).cp:
+            diag(Diagnostic("GALV031", f"cp={s.cp} on {cfg.name}, an "
+                            "architecture without ring attention", where=where))
         if mesh_constrained and s.cp > 1 and cp_w != s.cp:
             diag(Diagnostic("GALV032", f"cp={s.cp} but the mesh's cp axis is "
                             f"{cp_w if cp_w is not None else 'absent'}",

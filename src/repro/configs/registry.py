@@ -14,7 +14,7 @@ import importlib
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # dense | moe | ssm | hybrid | vlm | audio
+    family: str                  # dense | moe | ssm | hybrid | vlm | audio (configs/arch.py)
     num_layers: int
     d_model: int
     num_heads: int
@@ -61,19 +61,6 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // max(self.num_heads, 1)
-
-    @property
-    def is_attention_free(self) -> bool:
-        return self.family == "ssm"
-
-    @property
-    def is_subquadratic(self) -> bool:
-        """True if decode cost/state is sub-quadratic in context (SSM/hybrid)."""
-        return self.family in ("ssm", "hybrid")
-
-    @property
-    def is_encdec(self) -> bool:
-        return self.enc_layers > 0
 
     @property
     def ssm_heads(self) -> int:

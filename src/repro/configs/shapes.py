@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig
 
 
@@ -37,7 +38,7 @@ SHAPE_IDS = tuple(SHAPES)
 
 def supports_shape(cfg: ModelConfig, shape: ShapeSpec) -> tuple[bool, str]:
     """(runnable, reason). SKIP cells still appear in the dry-run table."""
-    if shape.sub_quadratic_only and not cfg.is_subquadratic:
+    if shape.sub_quadratic_only and not arch_of(cfg).sub_quadratic:
         return False, "long_500k needs sub-quadratic attention; this arch is full-attention"
     if shape.long_context_only and not cfg.long_context:
         return False, "train_32k needs a long-context config variant (cfg.long_context)"

@@ -13,10 +13,10 @@ Infeasible combinations are discarded structurally (the paper's take-aways):
   T5. Cost/memory-dominated candidates are pruned *after* costing
       (prune_dominated) — a leaf that is both slower and more memory-hungry
       than another can never be chosen by the DP.
-  T6. CP (ring flash-attention) only for dense-family attention blocks, and
-      only when the sequence splits into 2·cp zig-zag chunks
-      (context.validate_cp) — the same gate the runtime enforces, so a
-      searched cp plan can never fail to stage.
+  T6. CP (ring flash-attention) only for attention blocks of an
+      ``Arch.cp`` architecture, and only when the sequence splits into 2·cp
+      zig-zag chunks (context.validate_cp) — the same gate the runtime
+      enforces, so a searched cp plan can never fail to stage.
 
 ``mesh_constrained=True`` restricts TP to {1, model-axis width} — the
 degrees realizable on the fixed production mesh (DESIGN.md §4); the free
@@ -28,6 +28,7 @@ import math
 from typing import Optional
 
 from repro.analysis import invariants as inv
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig
 from repro.core.strategy import LayerStrategy, REMAT_POLICIES
 
@@ -47,12 +48,12 @@ def cp_candidates(cfg: ModelConfig, devices: int, *,
                   max_cp: Optional[int] = None) -> list[int]:
     """Context-parallel degrees realizable for one layer kind (T6).
 
-    Ring flash-attention is implemented for dense-family attention blocks;
+    Ring flash-attention is implemented for ``Arch.cp`` attention blocks;
     cp>1 additionally needs the zig-zag split to divide the sequence
     (seq_len % (2·cp) == 0).  ``mesh_constrained_cp`` restricts to {1, cp
     axis width}; ``max_cp`` caps the free-mode power-of-two enumeration
     (None => cp stays 1, the conservative default)."""
-    supported = layer_kind == "attn_block" and cfg.family == "dense"
+    supported = layer_kind == "attn_block" and arch_of(cfg).cp
     if not supported or seq_len is None:
         return [1]
     if mesh_constrained_cp is not None:

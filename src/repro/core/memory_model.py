@@ -16,6 +16,7 @@ interleaved holds a pp·(1+(v-1)/v) warm-up term.  Shared-weight groups
 from __future__ import annotations
 
 
+from repro.configs.arch import KV_KINDS, layer_slots
 from repro.core.cost_model import CostEnv
 from repro.core.profiler_model import LayerProfile, ModelProfile
 from repro.core.strategy import LayerStrategy
@@ -105,18 +106,14 @@ def plan_memory(model_profile: ModelProfile, strategies: list, env: CostEnv,
 
 
 def kv_cache_bytes(cfg, batch: int, seq_len: int) -> float:
-    """Serving-side cache size (global, bf16)."""
-    if cfg.family == "ssm":
-        di = cfg.ssm_expand * cfg.d_model
-        H = di // cfg.ssm_head_dim
-        per_layer = batch * (H * cfg.ssm_state * cfg.ssm_head_dim * 4.0
-                             + (cfg.conv_width - 1) * (di + 2 * cfg.ssm_groups * cfg.ssm_state) * 2.0)
-        return cfg.num_layers * per_layer
+    """Serving-side cache size (global, bf16): k/v per KV_KINDS layer, fp32
+    ssm state per mamba layer.  Conv state counts only when every layer is
+    mamba: a hybrid's leave it out (a known undercount, kept as pinned)."""
+    kinds = [slot.kind for slot in layer_slots(cfg)]
+    n_kv, n_ssm = sum(k in KV_KINDS for k in kinds), kinds.count("mamba_block")
+    di = cfg.ssm_expand * cfg.d_model
+    ssm = (di // cfg.ssm_head_dim) * cfg.ssm_state * cfg.ssm_head_dim * 4.0
+    if n_ssm == len(kinds):
+        ssm += (cfg.conv_width - 1) * (di + 2 * cfg.ssm_groups * cfg.ssm_state) * 2.0
     kv = 2.0 * batch * seq_len * cfg.num_kv_heads * cfg.resolved_head_dim * 2.0
-    if cfg.family == "hybrid":
-        di = cfg.ssm_expand * cfg.d_model
-        H = di // cfg.ssm_head_dim
-        mamba = cfg.num_layers * batch * (H * cfg.ssm_state * cfg.ssm_head_dim * 4.0)
-        return mamba + (cfg.num_layers // cfg.attn_every) * kv
-    layers = cfg.num_layers
-    return layers * kv
+    return n_ssm * batch * ssm + n_kv * kv

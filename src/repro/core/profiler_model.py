@@ -21,6 +21,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.configs.arch import arch_of, layer_slots
 from repro.configs.registry import ModelConfig
 
 
@@ -94,7 +95,7 @@ class ModelProfile:
 
 
 # --------------------------------------------------------------------------
-# analytic per-family profiles
+# analytic per-kind profiles
 # --------------------------------------------------------------------------
 
 def _attn_parts(cfg: ModelConfig, S: int, causal_frac: float) -> tuple[list, float]:
@@ -130,7 +131,7 @@ def _attn_acts(cfg: ModelConfig, S: int) -> tuple[float, float, float]:
 
 
 def _dense_block(cfg: ModelConfig, S: int, causal_frac: float, name: str,
-                 kind: str = "attn_block", shared: Optional[str] = None) -> LayerProfile:
+                 kind: str = "attn_block") -> LayerProfile:
     attn_parts, quad = _attn_parts(cfg, S, causal_frac)
     mlp_parts = _mlp_parts(cfg, S, cfg.d_ff)
     d, H, KV, hd, f = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
@@ -146,7 +147,7 @@ def _dense_block(cfg: ModelConfig, S: int, causal_frac: float, name: str,
         flop_parts=attn_parts + mlp_parts, flops_quadratic=quad,
         param_count=p_attn + p_bias + p_qknorm + p_mlp + p_norm,
         param_count_tp=p_attn + p_mlp,
-        shared_group=shared,
+        shared_group=None,
         act_inner=inner, act_boundary=boundary, act_selective_inner=sel,
         tp_collectives=2, ep_a2a_bytes=0.0,
         # k+v blocks, bf16.  The runtime rings k/v AFTER GQA expansion
@@ -189,7 +190,28 @@ def _moe_block(cfg: ModelConfig, S: int, causal_frac: float, name: str) -> Layer
     )
 
 
-def _mamba_block(cfg: ModelConfig, S: int, name: str) -> LayerProfile:
+def _dec_block(cfg: ModelConfig, S: int, causal_frac: float, name: str) -> LayerProfile:
+    """Self-attention block plus cross-attention (kv over enc frames)."""
+    blk = _dense_block(cfg, S, causal_frac, name, kind="dec_block")
+    d, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    F = cfg.enc_frames
+    cross = [
+        FlopPart(2.0 * S * d * H * hd, H),
+        FlopPart(2.0 * F * d * 2 * KV * hd, KV),
+        FlopPart(2.0 * S * F * H * hd * 2, H),
+        FlopPart(2.0 * S * H * hd * d, H),
+    ]
+    return dataclasses.replace(
+        blk,
+        flop_parts=blk.flop_parts + cross,
+        flops_quadratic=blk.flops_quadratic + cross[2].flops,
+        param_count=blk.param_count + 2 * d * H * hd + 2 * d * KV * hd + d,
+        param_count_tp=blk.param_count_tp + 2 * d * H * hd + 2 * d * KV * hd,
+        tp_collectives=3,
+    )
+
+
+def _mamba_block(cfg: ModelConfig, S: int, causal_frac: float, name: str) -> LayerProfile:
     d = cfg.d_model
     di = cfg.ssm_expand * d
     H = di // cfg.ssm_head_dim
@@ -223,52 +245,21 @@ def _mamba_block(cfg: ModelConfig, S: int, name: str) -> LayerProfile:
     )
 
 
+_PROFILERS = {"attn_block": _dense_block, "moe_block": _moe_block,
+              "mamba_block": _mamba_block, "dec_block": _dec_block,
+              "enc_block": lambda cfg, S, causal_frac, name:   # bidirectional
+              _dense_block(cfg, S, 1.0, name, kind="enc_block")}
+
+
 def profile_model(cfg: ModelConfig, seq_len: int, *, causal_frac: float = 1.0) -> ModelProfile:
     """causal_frac: 0.5 when the attention kernel skips the upper triangle."""
-    S = seq_len
+    S = seq_len   # vlm: seq_len already includes the vis prefix at call sites
     layers: list[LayerProfile] = []
-    if cfg.family in ("dense", "vlm", "moe"):
-        S_eff = S  # vlm: seq_len already includes the vis prefix at call sites
-        for i in range(cfg.num_layers):
-            if cfg.family == "moe":
-                layers.append(_moe_block(cfg, S_eff, causal_frac, f"layer{i}"))
-            else:
-                layers.append(_dense_block(cfg, S_eff, causal_frac, f"layer{i}"))
-    elif cfg.family == "ssm":
-        for i in range(cfg.num_layers):
-            layers.append(_mamba_block(cfg, S, f"layer{i}"))
-    elif cfg.family == "hybrid":
-        for i in range(cfg.num_layers):
-            layers.append(_mamba_block(cfg, S, f"mamba{i}"))
-            if (i + 1) % cfg.attn_every == 0:
-                layers.append(_dense_block(cfg, S, causal_frac, f"shared_attn@{i}",
-                                           shared="shared_attn"))
-    elif cfg.family == "audio":
-        for i in range(cfg.enc_layers):
-            layers.append(_dense_block(cfg, cfg.enc_frames, 1.0, f"enc{i}", kind="enc_block"))
-        for i in range(cfg.num_layers):
-            blk = _dense_block(cfg, S, causal_frac, f"dec{i}", kind="dec_block")
-            # add cross-attention (kv over enc frames)
-            d, H, KV, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-                            cfg.resolved_head_dim)
-            F = cfg.enc_frames
-            cross = [
-                FlopPart(2.0 * S * d * H * hd, H),
-                FlopPart(2.0 * F * d * 2 * KV * hd, KV),
-                FlopPart(2.0 * S * F * H * hd * 2, H),
-                FlopPart(2.0 * S * H * hd * d, H),
-            ]
-            blk = dataclasses.replace(
-                blk,
-                flop_parts=blk.flop_parts + cross,
-                flops_quadratic=blk.flops_quadratic + cross[2].flops,
-                param_count=blk.param_count + 2 * d * H * hd + 2 * d * KV * hd + d,
-                param_count_tp=blk.param_count_tp + 2 * d * H * hd + 2 * d * KV * hd,
-                tp_collectives=3,
-            )
-            layers.append(blk)
-    else:
-        raise ValueError(cfg.family)
+    for slot in layer_slots(cfg):
+        lp = _PROFILERS[slot.kind](cfg, getattr(cfg, slot.seq) if slot.seq else S,
+                                   causal_frac, slot.name)
+        lp.shared_group = slot.shared
+        layers.append(lp)
 
     embed_params = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2)
     head_flops = 2.0 * S * cfg.d_model * cfg.vocab_size
@@ -290,7 +281,7 @@ def _block_apply_fn(cfg: ModelConfig):
     from repro.models.common import init_params
 
     model = build_model(cfg)
-    if cfg.family in ("ssm", "hybrid"):
+    if arch_of(cfg).block_kind == "mamba_block":
         from repro.models.mamba2 import mamba_block_apply, mamba_block_defs
         params = init_params(mamba_block_defs(cfg), jax.random.PRNGKey(0))
         return params, lambda p, x: mamba_block_apply(p, x, cfg)[0]

@@ -17,6 +17,7 @@ from typing import Optional
 import numpy as np
 
 from repro.analysis import plan_check as pc
+from repro.configs.arch import arch_of, layer_slots
 from repro.configs.registry import ModelConfig
 from repro.core import calibrate as cal
 from repro.core import cost_model as cm
@@ -59,13 +60,8 @@ class SearchEngine:
                           mesh_cp: Optional[int] = None,
                           seq_len: Optional[int] = None,
                           mesh_constrained: bool = True) -> list[LayerStrategy]:
-        kinds = {"attn_block"}
-        if self.cfg.num_experts:
-            kinds.add("moe_block")
-        if self.cfg.family in ("ssm", "hybrid"):
-            kinds.add("mamba_block")
         seen: dict = {}
-        for kind in kinds:
+        for kind in dict.fromkeys(slot.kind for slot in layer_slots(self.cfg)):
             for s in candidate_strategies(
                     self.cfg, devices,
                     max_tp=min(self.cluster.intra_size, devices),
@@ -113,14 +109,14 @@ class SearchEngine:
             grad_accum_options = [g for g in (1, 2, 4, 8, 16, 32)
                                   if global_batch % g == 0]
 
-        sp_ok = cfg.family not in ("ssm",)   # SSD scan is sequential in seq
+        arch = arch_of(cfg)
         best: Optional[ExecutionPlan] = None
         best_time = INF
         evaluated = 0
         rejections: dict = {}
 
         for pp in pp_options:
-            if pp > 1 and (cfg.num_experts or not getattr_supports(cfg)):
+            if pp > 1 and (cfg.num_experts or not arch.pp):
                 continue                      # runtime gate (see train_pp)
             if pp > 1 and cfg.num_layers % pp != 0:
                 continue                      # stage_stack needs equal stages
@@ -128,7 +124,7 @@ class SearchEngine:
             cands = self._union_candidates(devices, mesh_tp, mesh_data,
                                            mesh_cp=mesh_cp, seq_len=seq_len,
                                            mesh_constrained=mesh_constrained)
-            if not sp_ok:
+            if not arch.sp:
                 cands = [c for c in cands if not c.sp]
             if cp_options is not None:
                 cands = [c for c in cands if c.cp in cp_options]
@@ -353,11 +349,6 @@ class SearchEngine:
                             prompt_len=prompt_len, slo=slo, **kw)
 
 
-def getattr_supports(cfg: ModelConfig) -> bool:
-    """PP runtime supports stacked-block families (see runtime/train_pp)."""
-    return cfg.family in ("dense", "vlm", "ssm")
-
-
 def evaluate_uniform(
     cfg: ModelConfig,
     cluster: ClusterSpec,
@@ -426,24 +417,21 @@ def to_runtime_strategies(cfg: ModelConfig, profile: ModelProfile,
                           choices: list) -> list:
     """Map per-profile-layer strategies onto the model's stacked blocks.
 
-    hybrid: shared-attn profile entries fold into the preceding mamba layer's
-    position (runtime is uniform for hybrid anyway); audio: enc+dec profile
-    entries -> decoder-length majority list.
+    A non-stacked architecture (hybrid, enc-dec) runs one uniform strategy:
+    the majority over its profile layers of the architecture's block kind,
+    repeated num_layers times.
 
-    Stacked-block families get their strategy multiset COALESCED into
+    Stacked architectures get their strategy multiset COALESCED into
     contiguous runs (stable by first appearance): the stack is homogeneous,
     so any permutation of the per-layer assignment has identical cost and
     memory, while contiguity minimizes scan-group count — the DP freely
     interleaves equal-cost strategies, which exploded compiled buffer usage
     4–7× before coalescing (measured: qwen3 train_4k 156 GB -> 36 GB)."""
-    if cfg.family == "hybrid":
-        mamba = [s for lp, s in zip(profile.layers, choices)
-                 if lp.kind == "mamba_block"]
-        maj = max(set(mamba), key=mamba.count)
-        return [maj] * cfg.num_layers
-    if cfg.family == "audio":
-        dec = [s for lp, s in zip(profile.layers, choices) if lp.kind == "dec_block"]
-        maj = max(set(dec), key=dec.count) if dec else choices[0]
+    arch = arch_of(cfg)
+    if not arch.stacked:
+        own = [s for lp, s in zip(profile.layers, choices)
+               if lp.kind == arch.block_kind]
+        maj = max(set(own), key=own.count) if own else choices[0]
         return [maj] * cfg.num_layers
     order: list = []
     counts: dict = {}

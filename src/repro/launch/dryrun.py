@@ -186,7 +186,7 @@ def run_cell(arch: str, shape_id: str, *, multi_pod: bool = False,
                    or plan.default_strategy.cp != cp):
         raise ValueError(
             f"no feasible cp={cp} plan for {arch}×{shape_id} "
-            f"(needs dense family + seq % (2*cp) == 0)")
+            f"(needs ring attention, Arch.cp, and seq % (2*cp) == 0)")
     if force_strategy is not None:                   # §Perf: pinned variants
         from repro.core.strategy import LayerStrategy
 

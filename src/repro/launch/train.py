@@ -36,6 +36,7 @@ import numpy as np
 from repro import obs
 from repro.analysis import plan_check
 from repro.analysis.invariants import cp_seq_divisible
+from repro.configs.arch import arch_of
 from repro.configs.registry import ARCH_IDS, ModelConfig, get_config
 from repro.core import calibrate
 from repro.core import profile_cache as pcache_lib
@@ -379,9 +380,9 @@ def main(argv=None):
         if not cp_seq_divisible(args.seq, args.cp):
             raise SystemExit(f"--cp {args.cp} needs --seq % (2*cp) == 0 "
                              f"(zig-zag split); got seq {args.seq}")
-        if cfg.family != "dense":
-            raise SystemExit(f"--cp supports dense-family archs; "
-                             f"{cfg.name} is {cfg.family}")
+        if not arch_of(cfg).cp:
+            raise SystemExit(f"--cp needs ring attention, which {cfg.name}'s "
+                             "architecture lacks (Arch.cp)")
     if n_dev == 1:
         if args.cp > 1:
             print(f"warning: --cp {args.cp} ignored on a single device")

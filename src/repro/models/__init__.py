@@ -1,32 +1,12 @@
-"""Model zoo: family dispatch."""
+"""Model zoo: the class each architecture names (``repro.configs.arch``)."""
 from __future__ import annotations
 
+import importlib
+
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig
 
 
 def build_model(cfg: ModelConfig, impl: str = "ref"):
-    if cfg.family in ("dense",):
-        from repro.models.transformer import DenseTransformerLM
-
-        return DenseTransformerLM(cfg, impl)
-    if cfg.family == "vlm":
-        from repro.models.transformer import VLMTransformerLM
-
-        return VLMTransformerLM(cfg, impl)
-    if cfg.family == "moe":
-        from repro.models.moe import MoETransformerLM
-
-        return MoETransformerLM(cfg, impl)
-    if cfg.family == "ssm":
-        from repro.models.mamba2 import Mamba2LM
-
-        return Mamba2LM(cfg, impl)
-    if cfg.family == "hybrid":
-        from repro.models.hybrid import HybridLM
-
-        return HybridLM(cfg, impl)
-    if cfg.family == "audio":
-        from repro.models.encdec import EncDecLM
-
-        return EncDecLM(cfg, impl)
-    raise ValueError(f"unknown family {cfg.family!r}")
+    module, cls = arch_of(cfg).model.split(":")
+    return getattr(importlib.import_module(module), cls)(cfg, impl)

@@ -17,8 +17,6 @@ from repro.parallel.axes import lc
 
 
 class EncDecLM:
-    supports_layer_grouping = False  # two stacks + cross-attn; uniform strategy
-
     def __init__(self, cfg: ModelConfig, impl: str = "ref"):
         self.cfg = cfg
         self.impl = impl

@@ -26,8 +26,6 @@ from repro.parallel.axes import lc
 
 
 class HybridLM(Mamba2LM):
-    supports_layer_grouping = False  # segment structure owns the stack layout
-
     def __init__(self, cfg: ModelConfig, impl: str = "ref"):
         super().__init__(cfg, impl)
         assert cfg.attn_every > 0

@@ -160,8 +160,6 @@ def mamba_block_apply(
 class Mamba2LM:
     """Pure-SSM LM (mamba2-2.7b)."""
 
-    supports_layer_grouping = True
-
     def __init__(self, cfg: ModelConfig, impl: str = "ref"):
         self.cfg = cfg
         self.impl = impl

@@ -45,8 +45,6 @@ def default_layer_runner(stacked_params, x, apply_block):
 
 
 class DenseTransformerLM:
-    supports_layer_grouping = True  # runtime may split the block stack
-
     def __init__(self, cfg: ModelConfig, impl: str = "ref"):
         self.cfg = cfg
         self.impl = impl

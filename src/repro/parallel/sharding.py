@@ -27,6 +27,7 @@ from typing import Optional
 import jax
 
 from repro.compat import Mesh, NamedSharding, P
+from repro.configs.arch import arch_of
 from repro.core.strategy import ExecutionPlan, LayerStrategy
 from repro.models.common import ParamDef
 from repro.parallel.axes import MeshRules
@@ -148,8 +149,7 @@ def param_spec_tree(
     kind="grad" : at stages >= 2.   kind="opt": at stages >= 1.
     """
     threshold = {"param": 3, "grad": 2, "opt": 1}[kind]
-    supports = getattr(model, "supports_layer_grouping", True)
-    grouped_mode = not plan.uniform() and supports
+    grouped_mode = not plan.uniform() and arch_of(model.cfg).stacked
     defs = model.param_defs()
 
     def rules_for(strategy: LayerStrategy) -> MeshRules:

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.arch import INPUT_LEN, arch_of
 from repro.configs.registry import ModelConfig
 from repro.configs.shapes import ShapeSpec
 
@@ -32,32 +33,27 @@ _FRAMES_STREAM = 7
 
 
 def _text_len(cfg: ModelConfig, seq_len: int) -> int:
-    if cfg.family == "vlm":
+    if "vis_embeds" in arch_of(cfg).inputs:
         return seq_len - cfg.vis_tokens
     return seq_len
+
+
+def _embed_inputs(cfg: ModelConfig, batch: int) -> dict:
+    """The architecture's embedding inputs: name -> (batch, length, d_model)."""
+    return {k: (batch, getattr(cfg, INPUT_LEN[k]), cfg.d_model)
+            for k in arch_of(cfg).inputs}
 
 
 def input_specs(cfg: ModelConfig, shape: ShapeSpec, model=None) -> dict:
     """Abstract inputs for train/prefill/decode lowering."""
     B, S = shape.global_batch, shape.seq_len
-    if shape.kind == "train":
-        st = _text_len(cfg, S)
-        out = {
-            "tokens": jax.ShapeDtypeStruct((B, st), jnp.int32),
-            "labels": jax.ShapeDtypeStruct((B, st), jnp.int32),
-        }
-        if cfg.family == "vlm":
-            out["vis_embeds"] = jax.ShapeDtypeStruct((B, cfg.vis_tokens, cfg.d_model), EMBED_DTYPE)
-        if cfg.family == "audio":
-            out["frames"] = jax.ShapeDtypeStruct((B, cfg.enc_frames, cfg.d_model), EMBED_DTYPE)
-        return out
-    if shape.kind == "prefill":
+    if shape.kind in ("train", "prefill"):
         st = _text_len(cfg, S)
         out = {"tokens": jax.ShapeDtypeStruct((B, st), jnp.int32)}
-        if cfg.family == "vlm":
-            out["vis_embeds"] = jax.ShapeDtypeStruct((B, cfg.vis_tokens, cfg.d_model), EMBED_DTYPE)
-        if cfg.family == "audio":
-            out["frames"] = jax.ShapeDtypeStruct((B, cfg.enc_frames, cfg.d_model), EMBED_DTYPE)
+        if shape.kind == "train":
+            out["labels"] = jax.ShapeDtypeStruct((B, st), jnp.int32)
+        for k, shp in _embed_inputs(cfg, B).items():
+            out[k] = jax.ShapeDtypeStruct(shp, EMBED_DTYPE)
         return out
     if shape.kind == "decode":
         assert model is not None, "decode specs need the model for its cache pytree"
@@ -110,19 +106,15 @@ class SyntheticDataset:
             "labels": toks[:, 1:].copy(),
         }
         embed_dtype = np.dtype(EMBED_DTYPE)   # match input_specs exactly
-        if self.cfg.family == "vlm":
-            batch["vis_embeds"] = np.zeros(
-                (len(ids), self.cfg.vis_tokens, self.cfg.d_model), embed_dtype)
-        if self.cfg.family == "audio":
+        for k, shp in _embed_inputs(self.cfg, len(ids)).items():
+            batch[k] = np.zeros(shp, embed_dtype)      # vis_embeds stay zero
+        if "frames" in batch:
             # per-sample Philox streams, like _tokens: frame content follows
             # the sample id, so any host layout yields the same global batch
             # and no two steps repeat frames
-            frames = np.empty(
-                (len(ids), self.cfg.enc_frames, self.cfg.d_model), embed_dtype)
             for row, sid in enumerate(ids):
                 g = np.random.Generator(np.random.Philox(
                     key=[self.seed * 1_000_003 + int(sid), _FRAMES_STREAM]))
-                frames[row] = g.standard_normal(
-                    (self.cfg.enc_frames, self.cfg.d_model)).astype(embed_dtype)
-            batch["frames"] = frames
+                batch["frames"][row] = g.standard_normal(
+                    batch["frames"].shape[1:]).astype(embed_dtype)
         return batch

@@ -17,10 +17,11 @@ from typing import Optional
 
 from repro.analysis import invariants as inv
 from repro.analysis import plan_check as pc
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig
 from repro.core import calibrate as cal
 from repro.core.cluster import ClusterSpec, TPU_V5E_POD
-from repro.core.search import SearchEngine, SearchResult, getattr_supports
+from repro.core.search import SearchEngine, SearchResult
 from repro.core.strategy import ExecutionPlan
 
 
@@ -116,11 +117,11 @@ def surviving_mesh(devices: int, *, model_axis: int = 16,
 def replan_pp_candidates(cfg: ModelConfig, devices: int, *,
                          max_pp: int = 8) -> list[int]:
     """Pipeline degrees a replan may retain: power-of-two stage counts the
-    runtime can realize on the surviving devices (stacked-block family, no
+    runtime can realize on the surviving devices (``Arch.pp``, no
     experts, layers split evenly, at least one full (data, model) plane per
     stage)."""
     out = [1]
-    if cfg.num_experts or not getattr_supports(cfg):
+    if cfg.num_experts or not arch_of(cfg).pp:
         return out
     pp = 2
     while (pp <= max_pp and devices // pp >= 1
@@ -133,11 +134,11 @@ def replan_pp_candidates(cfg: ModelConfig, devices: int, *,
 def replan_cp_candidates(cfg: ModelConfig, seq_len: int, devices: int, *,
                          max_cp: int = 4) -> list[int]:
     """Context-parallel degrees a replan may retain: ring attention is
-    implemented for dense attention stacks, needs the zig-zag split to
+    implemented where ``Arch.cp`` says so, needs the zig-zag split to
     divide the sequence, and cannot pay for itself below a few thousand
     tokens — short-context replans skip the extra searches entirely."""
     out = [1]
-    if cfg.family != "dense" or seq_len < 4096:
+    if not arch_of(cfg).cp or seq_len < 4096:
         return out
     cp = 2
     while (cp <= max_cp and devices // cp >= 1

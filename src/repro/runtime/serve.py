@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.compat import Mesh, NamedSharding, P
+from repro.configs.arch import arch_of
 from repro.core.strategy import ExecutionPlan
 from repro.parallel import sharding as shd
 from repro.parallel.axes import axis_rules
@@ -124,7 +125,7 @@ class ServingEngine:
         if paged is None:
             cfg = getattr(self.model, "cfg", None)
             paged = (self.mesh is None and cfg is not None
-                     and cfg.family == "dense")
+                     and arch_of(cfg).paged_serving)
         if not paged:
             return self.greedy_generate_reference(params, prompt_tokens,
                                                   max_new, max_len)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro import compat
 from repro import obs  # noqa: F401 -- importing it installs obs.gc_hook
 from repro.compat import Mesh, NamedSharding, P
+from repro.configs.arch import arch_of
 from repro.core.strategy import ExecutionPlan
 from repro.models.common import scoped
 from repro.parallel import sharding as shd
@@ -137,7 +138,7 @@ class HybridParallelModel:
     # ------------------------------------------------------------ params
     @property
     def _supports_grouping(self) -> bool:
-        return getattr(self.model, "supports_layer_grouping", True)
+        return arch_of(self.model.cfg).stacked
 
     def group(self, params):
         return shd.group_blocks(params, self.plan, self._supports_grouping)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.compat import Mesh, NamedSharding, P
+from repro.configs.arch import arch_of
 from repro.core.dynamic_programming import (interleave_realizable,
                                             schedule_windowable)
 from repro.core.strategy import ExecutionPlan
@@ -59,8 +60,8 @@ class PipelineTrainer:
 
     def __post_init__(self):
         assert self.plan.pp > 1
-        assert getattr(self.model, "supports_layer_grouping", True), \
-            "PP path needs a stacked-block model family"
+        assert arch_of(self.model.cfg).stacked, \
+            "PP path needs a stacked-block architecture"
         if self.model.cfg.num_experts:
             # XLA's SPMD partitioner check-fails on the MoE dispatch scatter
             # inside a partial-manual shard_map region (tracked upstream); MoE

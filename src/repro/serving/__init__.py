@@ -30,6 +30,7 @@ import dataclasses
 from typing import Any, Callable, Optional
 
 from repro.analysis import plan_check as pc
+from repro.configs.arch import arch_of
 from repro.configs.registry import ModelConfig, get_config
 from repro.core.cluster import TPU_V5E_POD, ClusterSpec
 from repro.core.strategy import ExecutionPlan, LayerStrategy
@@ -199,10 +200,10 @@ def build(config: ServeConfig, *, model: Any = None, params: Any = None,
     from repro.models.common import cast_tree
 
     cfg = config.model_config()
-    if cfg.family not in ("dense",):
+    if not arch_of(cfg).paged_serving:
         raise NotImplementedError(
-            f"paged serving supports the dense cache layout; family "
-            f"{cfg.family!r} still goes through step_engine()")
+            f"paged serving supports the dense cache layout; {cfg.name}'s "
+            "architecture still goes through step_engine()")
     if model is None:
         model = build_model(cfg)
     if params is None:

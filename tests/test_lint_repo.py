@@ -29,6 +29,17 @@ def test_direct_jit_flagged_and_compat_jit_clean():
     assert not _codes("from repro import compat\nf = compat.jit(g)\n")
 
 
+def test_family_read_flagged_outside_arch_module():
+    src = "def f(cfg):\n    return cfg.family == 'dense'\n"
+    assert _codes(src) == ["family-read"]
+    assert _codes("x = f'{cfg.family!r}'\n") == ["family-read"]
+    assert not _codes(src, rel="src/repro/configs/arch.py")
+    assert not _codes("from repro.configs.arch import arch_of\n"
+                      "ok = arch_of(cfg).cp\n")
+    for rel in ("tests/test_x.py", "benchmarks/x.py", "examples/x.py"):
+        assert "family-read" not in _codes(src, rel=rel)
+
+
 def test_shard_map_and_mesh_rules():
     assert "compat-shard-map" in _codes(
         "import jax\ns = jax.shard_map(f, mesh=m)\n")

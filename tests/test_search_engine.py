@@ -2,6 +2,7 @@
 plan feasibility for every assigned arch, cluster differentiation."""
 import numpy as np
 import pytest
+from tests import _arch_golden as _golden
 from tests._prop import given, settings, st
 
 from repro.configs.registry import ARCH_IDS, get_config
@@ -97,18 +98,22 @@ def test_prune_dominated_keeps_pareto():
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_search_feasible_on_production_mesh(arch):
     cfg = get_config(arch)
-    res = SearchEngine(cfg).search(4096, 256, mesh_shape=(16, 16),
-                                   mesh_axes=("data", "model"), pp_options=[1],
-                                   arch=arch, shape_name="train_4k")
+    searches = _golden.production_searches(arch)
+    # the plans pinned in tests/data/arch_golden.json, floats bit for bit
+    golden = _golden.load()
+    got = [_golden.plan_entry(r) for r in searches]
+    want = golden["plans"][arch]
+    if arch == "qwen3-14b":       # and the benchmark cell's tp2-sp-z3
+        got.append(_golden.plan_entry(_golden.cell_search()))
+        want = want + [golden["cell_plan"]]
+    assert [_golden.canonical(g) for g in got] == [_golden.canonical(w) for w in want]
+    res = searches[0]
     if arch == "grok-1-314b":
         # honest capacity result: 314B × 14 B/param of training state (fp32
         # master+grads, bf16 adam) = 4.4 TB > one pod's 4 TB HBM — every
         # strategy OOMs on 256 chips; two pods are feasible.
         assert not res.feasible
-        res2 = SearchEngine(cfg).search(4096, 256, mesh_shape=(2, 16, 16),
-                                        mesh_axes=("pod", "data", "model"),
-                                        pp_options=[1], arch=arch)
-        assert res2.feasible
+        assert searches[1].feasible
         return
     assert res.feasible, arch
     plan = res.plan
